@@ -5,7 +5,7 @@ Compares, at several cluster sizes / removal ratios, µs-per-key of:
   * the unified engine's jnp program (jit; CPU backend here, TPU in
     production),
   * the unified engine's Pallas launch in interpret mode (correctness
-    path; Mosaic on real TPU).
+    path; off-TPU only — Mosaic cannot compile Memento's table gather).
 
 Both device rows are the SAME ``EngineOp`` configuration (DESIGN.md §6) —
 only the plane differs.  Interpret-mode timings are NOT TPU performance —
@@ -24,7 +24,7 @@ def bench_device_plane(emit, sizes=((1024, 0), (1024, 300), (65536, 2000)),
                        n_keys=16384):
     import jax.numpy as jnp
     from repro.core import random_state
-    from repro.kernels.engine import engine_lookup
+    from repro.kernels.engine import default_interpret, engine_lookup
 
     keys = np.random.default_rng(0).integers(0, 2**32, size=n_keys, dtype=np.uint32)
     jkeys = jnp.asarray(keys)
@@ -48,10 +48,11 @@ def bench_device_plane(emit, sizes=((1024, 0), (1024, 300), (65536, 2000)),
         emit("device_plane", "jnp_batched", tag, "us_per_key",
              (time.perf_counter() - t0) / (5 * n_keys) * 1e6)
 
-        out2 = engine_lookup(jkeys, image, plane="pallas", interpret=True)
+        if not default_interpret():
+            continue  # the interpreter is an off-TPU correctness path only
+        out2 = engine_lookup(jkeys, image, plane="pallas")
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
         t0 = time.perf_counter()
-        engine_lookup(jkeys, image, plane="pallas",
-                      interpret=True).block_until_ready()
+        engine_lookup(jkeys, image, plane="pallas").block_until_ready()
         emit("device_plane", "pallas_interpret", tag, "us_per_key",
              (time.perf_counter() - t0) / n_keys * 1e6)

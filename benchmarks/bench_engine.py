@@ -17,10 +17,11 @@ Two stories (DESIGN.md §6), for the four algorithms across the paper's
 * **scale-out** — single-device engine throughput vs the mesh-sharded
   :class:`~repro.serve.plane.ShardedLookupPlane` for 10⁵–10⁷-key batches
   (``--full`` reaches 10⁷), with sharded == single-device equality
-  asserted.  Run standalone (``python -m benchmarks.bench_engine``) the
-  module forces ``--xla_force_host_platform_device_count=2`` BEFORE jax
-  initializes, so even the CPU container exercises a real 2-device mesh;
-  under ``benchmarks.run --engine`` it uses whatever devices exist.
+  asserted.  Run standalone (``python -m benchmarks.bench_engine``) with
+  ``JAX_PLATFORMS=cpu``, the module forces
+  ``--xla_force_host_platform_device_count=2`` BEFORE jax initializes, so
+  the CPU backend exercises a real 2-device mesh; elsewhere, and under
+  ``benchmarks.run --engine``, it uses whatever devices exist.
 
 Correctness gates are deterministic and CI-hard (``check_engine_claims``);
 timings — including the ≥1.8× two-device target at 10⁶ keys — are
@@ -412,10 +413,13 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # Force a 2-device host platform BEFORE jax initializes so the CPU
-    # container exercises a real mesh (the dry-run launcher's trick).
-    if "--xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
+    # On the CPU backend, force a 2-device host platform BEFORE jax
+    # initializes so the sharded plane runs over a real mesh.  Only there:
+    # on a chip the mesh is the chip's devices, and a simulated one must
+    # never be read as a chip result.
+    if os.environ.get("JAX_PLATFORMS") == "cpu" and \
+            "--xla_force_host_platform_device_count" not in os.environ.get(
+                "XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=2").strip()

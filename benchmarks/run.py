@@ -242,4 +242,7 @@ def check_paper_claims(rows) -> bool:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile of the run
     sys.exit(main())

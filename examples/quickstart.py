@@ -4,8 +4,7 @@
 """
 import numpy as np
 
-from repro.core import (AnchorHash, DxHash, JumpHash, MementoHash,
-                        MementoTables, PowerHash)
+from repro.core import AnchorHash, DxHash, JumpHash, MementoHash, PowerHash
 from repro.kernels import ops
 
 
@@ -27,12 +26,11 @@ def main():
     print("new tail node:", m.add())
     print(f"state: n={m.n} |R|={len(m.R)}")
 
-    # 4. the device data plane: bulk lookups via the Pallas kernel
+    # 4. the device data plane: bulk lookups through the lookup engine
     m.remove(7)
     m.remove(2)
-    tabs = MementoTables(m)
     batch = np.random.default_rng(0).integers(0, 2**32, size=8, dtype=np.uint32)
-    out = ops.memento_lookup(batch, tabs.repl, tabs.n)  # interpret on CPU
+    out = ops.device_lookup(batch, m.device_image(), plane="auto")
     print("\nbatched device-plane lookups:", np.asarray(out).tolist())
 
     # 5. baselines for comparison (fixed capacity a = 10·w)
@@ -50,7 +48,7 @@ def main():
             h.remove(h.size - 1)
         else:
             h.remove(3)
-        out = ops.device_lookup(batch, h.device_image())  # Pallas (interpret on CPU)
+        out = ops.device_lookup(batch, h.device_image(), plane="auto")
         assert [h.lookup(int(k)) for k in batch] == np.asarray(out).tolist()
         print(f"  {algo:8s} → {np.asarray(out).tolist()}")
 

@@ -190,8 +190,8 @@ class DeviceImageStore:
         self.compact = compact
         self._mirror: dict | None = None  # host copy of the packed arrays
         if interpret is None:
-            import jax
-            interpret = jax.default_backend() != "tpu"
+            from repro.kernels.engine import default_interpret
+            interpret = default_interpret()
         self._interpret = interpret
         self.totals = SyncTotals()
         self.last_sync: SyncStats | None = None

@@ -102,8 +102,8 @@ def dx_lookup(keys, words, a, max_probes, fallback):
     unsettled lanes take the precomputed first-working ``fallback``."""
     keys = jnp.asarray(keys).astype(_U)
     au = jnp.asarray(a).astype(_U)
-    b0 = jnp.zeros(keys.shape, jnp.int32)
-    found0 = jnp.zeros(keys.shape, jnp.bool_)
+    b0 = jnp.zeros_like(keys, jnp.int32)
+    found0 = jnp.zeros_like(keys, jnp.bool_)
 
     def cond(state):
         i, _, found = state

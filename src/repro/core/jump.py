@@ -5,9 +5,10 @@ Two variants (DESIGN.md §3):
 * ``jump64``: the paper-faithful 64-bit LCG implementation (the exact
   pseudo-code from arXiv:1406.2294).
 * ``jump32``: the TPU-native variant.  Each step's uniform variate comes from
-  a murmur3-mixed (key, step) hash and the divide runs in float32, matching
-  the device data plane bit-for-bit (numpy f32 and XLA f32 divisions are both
-  IEEE correctly-rounded, so host and device agree exactly).
+  a murmur3-mixed (key, step) hash and the divide runs in float32, correctly
+  rounded (IEEE).  The device data plane matches it bit-for-bit; a TPU's
+  float32 divide is not correctly rounded, so the device settles each step
+  on integers (``kernels/primitives.floor_rn_quotient``).
 """
 from __future__ import annotations
 

@@ -15,11 +15,12 @@ dispatch time:
   static jit key every time).
 * **override** — an explicit ``block_rows=`` at any entry point always
   wins; an absent cache entry falls back to
-  :data:`~repro.kernels.engine.DEFAULT_BLOCK_ROWS` (and the Pallas plane
-  on TPU / jnp elsewhere for ``plane="auto"`` callers).
+  :data:`~repro.kernels.engine.DEFAULT_BLOCK_ROWS` (and, for
+  ``plane="auto"`` callers, the Pallas plane on TPU where Mosaic compiles
+  the op, jnp otherwise).
 * **correctness** — every candidate's output is asserted bit-identical to
-  the default configuration before it may win; tuning can change *time*,
-  never placement.
+  the jnp program before it may win; tuning can change *time*, never
+  placement.
 
 The cache path can be redirected with ``REPRO_TUNE_CACHE=/path.json``
 (tests point it at a tmpdir; ``REPRO_TUNE_CACHE=`` disables loading).
@@ -184,11 +185,19 @@ def resolve_plane(op, n_keys: int, table_n: int,
                   backend: str | None = None) -> str:
     """Plane for ``plane="auto"`` callers: the tuned winner, else Pallas on
     TPU (the compiled kernel) and jnp elsewhere (interpret-mode Pallas is
-    a correctness path, not a serving plane)."""
+    a correctness path, not a serving plane).  On TPU the answer is never
+    a Pallas configuration Mosaic cannot compile
+    (:func:`~repro.kernels.engine.mosaic_compiles`): such ops resolve to
+    jnp whatever the cache says."""
+    from .engine import mosaic_compiles
+
+    backend = backend or _backend()
     cfg = lookup_tuned(op, n_keys, table_n, backend)
-    if cfg is not None:
-        return cfg.plane
-    return "pallas" if (backend or _backend()) == "tpu" else "jnp"
+    plane = cfg.plane if cfg is not None else (
+        "pallas" if backend == "tpu" else "jnp")
+    if plane == "pallas" and backend == "tpu" and not mosaic_compiles(op):
+        return "jnp"
+    return plane
 
 
 # ---------------------------------------------------------------------------
@@ -212,28 +221,25 @@ def autotune_lookup(image, n_keys: int, *, k: int = 1, seed: int = 0,
                     backend: str | None = None) -> tuple[str, TunedConfig]:
     """Tune one grid cell: measure ``engine_lookup`` over every (plane,
     block_rows) candidate at this (image, batch) shape, assert every
-    candidate bit-identical to the default configuration, record the
+    candidate bit-identical to the jnp program, record the
     fastest in ``cache`` (default: the active cache) and return
     ``(grid key, winner)``."""
-    from .engine import DEFAULT_BLOCK_ROWS, EngineOp, engine_lookup
+    from .engine import (DEFAULT_BLOCK_ROWS, EngineOp, default_interpret,
+                         engine_lookup, mosaic_compiles)
 
     op = EngineOp(algo=image.algo, k=k,
                   table="packed" if getattr(image, "packed", False)
                   else "dense")
     keys = np.random.default_rng(seed).integers(0, 2**32, size=n_keys,
                                                 dtype=np.uint32)
-    ref = np.asarray(engine_lookup(keys, image, k=k, plane="pallas",
-                                   block_rows=DEFAULT_BLOCK_ROWS))
+    ref = np.asarray(engine_lookup(keys, image, k=k, plane="jnp"))
     measured: list[tuple[float, str, int]] = []
     if "jnp" in planes:
         t = _time_best(lambda: engine_lookup(keys, image, k=k, plane="jnp"),
                        repeats)
-        out = np.asarray(engine_lookup(keys, image, k=k, plane="jnp"))
-        if not np.array_equal(out, ref):
-            raise AssertionError("jnp plane diverged from the default "
-                                 f"configuration for {op_tag(op)}")
         measured.append((t, "jnp", DEFAULT_BLOCK_ROWS))
-    if "pallas" in planes:
+    # on a TPU, Pallas is a candidate only where Mosaic compiles the op
+    if "pallas" in planes and (default_interpret() or mosaic_compiles(op)):
         for br in candidates:
             t = _time_best(lambda: engine_lookup(keys, image, k=k,
                                                  plane="pallas",
@@ -242,8 +248,8 @@ def autotune_lookup(image, n_keys: int, *, k: int = 1, seed: int = 0,
                                            block_rows=br))
             if not np.array_equal(out, ref):
                 raise AssertionError(
-                    f"block_rows={br} diverged from the default "
-                    f"configuration for {op_tag(op)}")
+                    f"pallas block_rows={br} diverged from the jnp "
+                    f"program for {op_tag(op)}")
             measured.append((t, "pallas", br))
     if not measured:
         raise ValueError("no candidate planes to tune over")

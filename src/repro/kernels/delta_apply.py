@@ -11,10 +11,11 @@ is materialized.
 
 Scatter layout: the update indices/values ride in the scalar-prefetch
 operand (SMEM), bounded by a dynamic ``count`` so one compiled kernel
-serves any delta up to the padded width; each update turns into a masked
-vector select over the (rows, 128) table block — O(count · n/8·128 VPU
-steps), which for the O(1)-word deltas the algorithms emit is a handful of
-vector ops.  uint32 tables (the Dx bitmap) are bit-cast through int32 so
+serves any delta up to the padded width.  The grid walks the (rows, 128)
+table in ``APPLY_BLOCK_ROWS``-row blocks, and each update turns into a
+masked vector select over each block — O(count · n/1024 VPU steps), which
+for the O(1)-word deltas the algorithms emit is a handful of vector ops
+per block.  uint32 tables (the Dx bitmap) are bit-cast through int32 so
 the one kernel covers every image array.
 """
 from __future__ import annotations
@@ -30,13 +31,20 @@ from jax.experimental.pallas import tpu as pltpu
 from .primitives import table_shape2d
 
 
+#: table rows per grid step of the apply kernel: (512, 128) int32 = 256 KiB
+#: per block, so input + output double buffers stay far inside VMEM at any
+#: table size (a 10⁶-bucket dense image is 7,813 such rows).
+APPLY_BLOCK_ROWS = 512
+
+
 def _apply_kernel(meta_ref, table_ref, out_ref):
     # meta = [count, idx_0..idx_{P-1}, val_0..val_{P-1}] (int32, SMEM)
     count = meta_ref[0]
     pad = (meta_ref.shape[0] - 1) // 2
     tab = table_ref[...]
     rows, cols = tab.shape
-    flat = (lax.broadcasted_iota(jnp.int32, (rows, cols), 0) * cols
+    first = pl.program_id(0) * (rows * cols)  # flat index of this block
+    flat = (first + lax.broadcasted_iota(jnp.int32, (rows, cols), 0) * cols
             + lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
 
     def body(j, acc):
@@ -48,14 +56,20 @@ def _apply_kernel(meta_ref, table_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _apply_scatter_i32(meta, table2d, *, interpret: bool = True):
+def _apply_scatter_i32(meta, table2d, *, interpret: bool):
+    """Grid over row blocks of the table: each step copies its block and
+    applies the updates that land in it (a block never holds the whole
+    table, so VMEM use is independent of the fleet size)."""
+    rows, cols = table2d.shape
+    block = (min(rows, APPLY_BLOCK_ROWS), cols)
+    spec = pl.BlockSpec(block, lambda i, m: (i, 0))
     return pl.pallas_call(
         _apply_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[pl.BlockSpec(table2d.shape, lambda i, m: (0, 0))],
-            out_specs=pl.BlockSpec(table2d.shape, lambda i, m: (0, 0)),
+            grid=(pl.cdiv(rows, block[0]),),
+            in_specs=[spec],
+            out_specs=spec,
         ),
         out_shape=jax.ShapeDtypeStruct(table2d.shape, jnp.int32),
         interpret=interpret,
@@ -123,7 +137,7 @@ def compose_updates(update_seq) -> dict:
 
 
 def apply_updates(arrays: dict, updates: dict, *, plane: str = "jnp",
-                  interpret: bool = True) -> dict:
+                  interpret: bool | None = None) -> dict:
     """Apply per-array ``{name: (idx, vals)}`` scatters to an image's
     ``arrays`` dict, out of place.
 
@@ -145,11 +159,12 @@ def apply_updates(arrays: dict, updates: dict, *, plane: str = "jnp",
 
 
 def scatter_update(table, idx, vals, *, plane: str = "jnp",
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Out-of-place scatter ``table[idx] = vals`` → new device array.
 
     ``plane='jnp'`` uses a functional ``.at[].set`` (any backend);
-    ``plane='pallas'`` runs the apply-delta kernel (interpret off-TPU).
+    ``plane='pallas'`` runs the apply-delta kernel (interpret off-TPU;
+    ``interpret=None`` follows :func:`~repro.kernels.engine.default_interpret`).
     Either way the input buffer is preserved — the caller keeps it as the
     previous-epoch half of its double buffer.
     """
@@ -171,6 +186,9 @@ def scatter_update(table, idx, vals, *, plane: str = "jnp",
         # the int32 apply kernel (widths differ); their scatter payloads are
         # O(1) words, so the functional path serves them on every backend.
         return scatter_update(table, idx, vals, plane="jnp")
+    if interpret is None:
+        from .engine import default_interpret
+        interpret = default_interpret()
     pidx, pval, k = _pad_updates(np.asarray(idx), np.asarray(vals), sentinel=-1)
     meta = jnp.asarray(np.concatenate([[k], pidx, pval]).astype(np.int32))
     tab_i32 = lax.bitcast_convert_type(table, jnp.int32)

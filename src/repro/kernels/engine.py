@@ -30,7 +30,9 @@ and only here; this module is the one import surface for device lookups
 (the per-algorithm re-export shims of the engine's first release are
 retired).
 
-Planes: ``plane='pallas'`` (Mosaic on TPU, interpret elsewhere) and
+Planes: ``plane='pallas'`` (Mosaic on TPU for the configurations
+:func:`mosaic_compiles` admits — any other refuses at dispatch —,
+interpret elsewhere) and
 ``plane='jnp'`` (pure-jnp, any backend; also the per-shard body the
 mesh-sharded :class:`~repro.serve.plane.ShardedLookupPlane` runs under
 ``shard_map``).  Both are bit-identical to the host control plane on
@@ -63,8 +65,24 @@ _U = jnp.uint32
 DEFAULT_BLOCK_ROWS = 8  # (8, 128) keys per program = 1024 lookups
 
 
-def _default_interpret() -> bool:
+def default_interpret() -> bool:
+    """THE interpret-mode rule for every Pallas kernel in the repo: Mosaic
+    on a TPU backend, the Pallas interpreter everywhere else."""
     return jax.default_backend() != "tpu"
+
+
+#: algorithms whose lookup body Mosaic compiles: the stateless ones.  The
+#: table-backed bodies (Memento ``repl``/slots, Anchor A/K, Dx bitmap) and
+#: every load-word mode need a 1-D gather from a VMEM table, which Mosaic
+#: does not lower ("Only 2D gather is supported"), and k > 1 replica walks
+#: start splat-constant lane carries Mosaic cannot lay out (DESIGN.md §6).
+MOSAIC_ALGOS = ("jump", "power")
+
+
+def mosaic_compiles(op: "EngineOp") -> bool:
+    """Can the Pallas plane run ``op`` compiled (not interpreted) on a TPU?"""
+    return (op.algo in MOSAIC_ALGOS and op.mode == "lookup" and op.k == 1
+            and not op.bounded)
 
 
 def _resolve_block_rows(op, n_keys: int, table_n: int,
@@ -234,8 +252,8 @@ def compact_reader(slot_b, slot_c):
             pos = jnp.where(done, pos, (pos + 1) % nslots)
             return pos, done, val
 
-        val0 = jnp.full(idx.shape, -1, jnp.int32)
-        done0 = jnp.zeros(idx.shape, jnp.bool_)
+        val0 = jnp.full_like(idx, -1, jnp.int32)
+        done0 = jnp.zeros_like(idx, jnp.bool_)
         _, _, val = jax.lax.while_loop(cond, body, (h0, done0, val0))
         return val
 
@@ -273,7 +291,7 @@ def packed_reader(state, slot_b, slot_c):
             pos = jnp.where(done, pos, (pos + 1) % nslots)
             return pos, done, val
 
-        val0 = jnp.full(idx.shape, -1, jnp.int32)
+        val0 = jnp.full_like(idx, -1, jnp.int32)
         _, _, val = jax.lax.while_loop(cond, body, (h0, working, val0))
         return val
 
@@ -308,8 +326,8 @@ def anchor_body(keys, A, K, a):
 
 def dx_body(keys, words, a, max_probes, fallback):
     """DxHash body: pseudo-random probing of the packed active bitmap."""
-    b0 = jnp.zeros(keys.shape, jnp.int32)
-    found0 = jnp.zeros(keys.shape, jnp.bool_)
+    b0 = jnp.zeros_like(keys, jnp.int32)
+    found0 = jnp.zeros_like(keys, jnp.bool_)
 
     def cond(state):
         i, _, found = state
@@ -381,10 +399,10 @@ def replica_body(keys, k, single_lookup, load=None, cap=None):
         if k == 1:
             return [first]
         outs: list = [first]
-        salt = jnp.ones(keys.shape, jnp.int32)
+        salt = jnp.ones_like(keys, jnp.int32)
     else:
         outs = []  # bounded: slot 0 walks too (cap check on the primary)
-        salt = jnp.zeros(keys.shape, jnp.int32)
+        salt = jnp.zeros_like(keys, jnp.int32)
     for _ in range(k - len(outs)):
         prev = tuple(outs)
 
@@ -398,7 +416,7 @@ def replica_body(keys, k, single_lookup, load=None, cap=None):
             cand = single_lookup(hash2(keys, salt))
             if load is not None:  # only bounded lanes can sit at salt 0
                 cand = jnp.where(salt == 0, first, cand)
-            bad = jnp.zeros(keys.shape, jnp.bool_)
+            bad = jnp.zeros_like(keys, jnp.bool_)
             for o in prev:
                 bad = bad | (cand == o)
             if load is not None:
@@ -409,7 +427,7 @@ def replica_body(keys, k, single_lookup, load=None, cap=None):
             return salt, slot, done | ok
 
         salt, slot, _ = jax.lax.while_loop(
-            cond, body, (salt, first, jnp.zeros(keys.shape, jnp.bool_)))
+            cond, body, (salt, first, jnp.zeros_like(keys, jnp.bool_)))
         outs.append(slot)
     return outs
 
@@ -517,6 +535,12 @@ def _engine_kernel_factory(op: EngineOp):
                    static_argnames=("op", "block_rows", "interpret"))
 def _engine_pallas(scalars, blocks2d, tables2d, *, op: EngineOp,
                    block_rows: int, interpret: bool):
+    if not interpret and not mosaic_compiles(op):
+        from .autotune import op_tag
+        raise ValueError(
+            f"the Pallas engine cannot compile {op_tag(op)} with Mosaic "
+            f"(compiled Pallas serves {'/'.join(MOSAIC_ALGOS)} k=1 lookups "
+            "and diffs); use plane='jnp' or plane='auto'")
     rows = blocks2d[0].shape[0]
     block_rows = min(block_rows, rows)
     grid = (-(-rows // block_rows),)
@@ -662,7 +686,7 @@ def engine_lookup(keys, image, *, k: int = 1, load=None, cap: int | None = None,
         raise ValueError(f"unknown plane {plane!r}")
     else:
         if interpret is None:
-            interpret = _default_interpret()
+            interpret = default_interpret()
         tables = _image_tables(op, image)
         if bounded:
             tables.append(jnp.asarray(load, jnp.int32))
@@ -792,7 +816,7 @@ def _engine_diff(keys, old_image, new_image, *, k: int = 1,
     op = EngineOp(algo=old_image.algo, k=k, diff=True,
                   table=_op_table(old_image))
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     tables = _image_tables(op, old_image) + _image_tables(op, new_image)
     keys2d, nk = _pad_rows(keys)
     outs = _engine_pallas(_scalar_vec(op, [old_image, new_image], None),
@@ -837,7 +861,7 @@ def engine_chain_walk(chain, probe, pending, image, load, cap, *,
     if plane != "pallas":
         raise ValueError(f"unknown plane {plane!r}")
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     nk = chain.shape[0]
     chain2d, _ = _pad_rows(chain)
     probe2d, _ = _pad_rows(probe)
@@ -925,60 +949,6 @@ def bounded_replica_sets(h, keys, k: int, load, cap: int) -> np.ndarray:
     for i, key in enumerate(keys):
         out[i] = h.lookup_k_filtered(int(key), k, reject, check_first=True)
     return out
-
-
-# ---------------------------------------------------------------------------
-# Raw-array entry points (the legacy per-algorithm kernel signatures, kept
-# so the shim modules stay pure re-exports)
-# ---------------------------------------------------------------------------
-
-def _raw_lookup(op: EngineOp, tables, scalars, keys, block_rows, interpret):
-    keys2d, nk = _pad_rows(jnp.asarray(keys).astype(_U))
-    outs = _engine_pallas(jnp.asarray(scalars, jnp.int32), (keys2d,),
-                          tuple(_tables2d([jnp.asarray(t) for t in tables])),
-                          op=op, block_rows=block_rows, interpret=interpret)
-    return outs[0].reshape(-1)[:nk]
-
-
-def dense_lookup(keys, repl, n, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-                 interpret: bool = True):
-    """Batched Memento lookup with the dense Θ(n)-int32 table in VMEM."""
-    return _raw_lookup(EngineOp("memento"), [repl], [n], keys,
-                       block_rows, interpret)
-
-
-def compact_lookup(keys, slot_b, slot_c, n, *,
-                   block_rows: int = DEFAULT_BLOCK_ROWS,
-                   interpret: bool = True):
-    """Batched Memento lookup with the Θ(r) open-addressing table in VMEM."""
-    return _raw_lookup(EngineOp("memento", table="compact"),
-                       [slot_b, slot_c], [n], keys, block_rows, interpret)
-
-
-def anchor_lookup(keys, A, K, a, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-                  interpret: bool = True):
-    """Batched AnchorHash lookup: keys uint32 [K] → working bucket ids."""
-    return _raw_lookup(EngineOp("anchor"), [A, K], [a], keys,
-                       block_rows, interpret)
-
-
-def dx_lookup(keys, words, a, max_probes, fallback, *,
-              block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True):
-    """Batched DxHash lookup: keys uint32 [K] → working bucket ids."""
-    return _raw_lookup(EngineOp("dx"), [words], [a, max_probes, fallback],
-                       keys, block_rows, interpret)
-
-
-def jump_lookup(keys, n, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-                interpret: bool = True):
-    """Batched JumpHash lookup: keys uint32 [K] → bucket ids in [0, n)."""
-    return _raw_lookup(EngineOp("jump"), [], [n], keys, block_rows, interpret)
-
-
-def power_lookup(keys, n, *, block_rows: int = DEFAULT_BLOCK_ROWS,
-                 interpret: bool = True):
-    """Batched PowerHash lookup: keys uint32 [K] → bucket ids in [0, n)."""
-    return _raw_lookup(EngineOp("power"), [], [n], keys, block_rows, interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -11,13 +11,14 @@ Execution planes:
 
   * ``plane='pallas'`` — the engine's Pallas launch (default).  On non-TPU
     backends it runs in interpret mode (the validation path); on TPU it
-    compiles via Mosaic.
+    compiles via Mosaic where :func:`~repro.kernels.engine.mosaic_compiles`
+    admits the configuration and refuses otherwise.
   * ``plane='jnp'``    — the engine's pure-jnp program (no Pallas; any
     backend; also the per-shard body of the mesh-sharded
     :class:`~repro.serve.plane.ShardedLookupPlane`).
   * ``plane='auto'``   — the autotuner's winner for this (op, batch,
     table-size) cell (``kernels/autotune.py``), falling back to Pallas on
-    TPU and jnp elsewhere when no tuning entry exists.
+    TPU where Mosaic compiles the op and jnp otherwise.
 
 Table layouts (``table``):
 
@@ -29,15 +30,10 @@ Table layouts (``table``):
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import jax_lookup as _jnp
 from . import engine as _engine
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def device_lookup(keys, image, *, plane: str = "pallas", table: str = "dense",
@@ -65,25 +61,3 @@ def device_lookup(keys, image, *, plane: str = "pallas", table: str = "dense",
                                  plane=plane, table=table,
                                  interpret=interpret,
                                  block_rows=block_rows)
-
-
-def memento_lookup(keys, repl, n, *, table: str = "dense", interpret: bool | None = None):
-    """Batched Alg. 4 lookup: keys uint32 [K] → working bucket ids int32 [K]."""
-    keys = jnp.asarray(keys, dtype=jnp.uint32)
-    repl = jnp.asarray(repl, dtype=jnp.int32)
-    if interpret is None:
-        interpret = _default_interpret()
-    if table == "jnp":
-        return _jnp.memento_lookup(keys, repl, n)
-    if table == "dense":
-        return _engine.dense_lookup(keys, repl, n, interpret=interpret)
-    if table == "compact":
-        slot_b, slot_c = _engine.build_compact_table(repl)
-        return _engine.compact_lookup(keys, slot_b, slot_c, n,
-                                      interpret=interpret)
-    raise ValueError(f"unknown table kind {table!r}")
-
-
-def lookup_from_tables(keys, tables, **kw):
-    """Route against a host :class:`repro.core.MementoTables`."""
-    return memento_lookup(keys, tables.repl, tables.n, **kw)

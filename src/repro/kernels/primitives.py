@@ -2,13 +2,18 @@
 
 One implementation of the TPU-native 32-bit arithmetic (DESIGN.md §3.1),
 consumed by BOTH the pure-jnp oracles (``core/jax_lookup.py``) and the
-Pallas kernels (``kernels/*_lookup.py``) — every op here lowers cleanly
-inside a Pallas kernel body and under plain jit.
+Pallas engine (``kernels/engine.py``).  Everything here runs under plain
+jit and in interpret-mode Pallas; Mosaic (Pallas on TPU) compiles
+``fmix32``/``hash2``/``jump32``/``power32`` but not ``gather1d``, a 1-D
+gather Mosaic has no lowering for (DESIGN.md §6).  Per-lane loop carries
+start from ``*_like(keys)`` so that, under ``jax.shard_map``, they carry
+the same varying mesh axes as the keys that update them.
 
 Bit-identical to the numpy/scalar host plane in ``core/hashing.py`` and
-``core/jump.py``: murmur3 fmix32 mixing, 24-bit uniform variates, exact
-f32 divides.  Constants are imported from ``core/hashing`` so there is a
-single definition in the repo.
+``core/jump.py``: murmur3 fmix32 mixing, 24-bit uniform variates, the
+host's correctly rounded f32 jump step decided on integers.  Constants
+are imported from ``core/hashing`` so there is a single definition in
+the repo.
 """
 from __future__ import annotations
 
@@ -48,33 +53,82 @@ def step_u24(keys, step):
     return h >> _U(8)
 
 
+def floor_rn_quotient(x, den, approx, n):
+    """``min(⌊fl32(x·2²⁴ / den)⌋, n)`` exactly, from any ``approx`` of the
+    quotient within a few ulps.
+
+    The host's jump step divides in float32 and rounds to nearest
+    (IEEE); a TPU's float32 divide is not correctly rounded, so the device
+    cannot simply repeat it.  Here the float quotient is only a first
+    guess: the exact integer quotient and remainder follow from 32-bit
+    wrapping arithmetic (the remainder is small, so its low 32 bits are
+    the whole of it), and round-to-nearest is then decided on integers.
+    ``x`` ≥ 1 and ``den`` ∈ [1, 2²⁴] are int32 arrays, ``n`` ≤ 2²⁴ the
+    clamp (``int32``).
+    """
+    i32 = jnp.int32
+    nf = n.astype(jnp.float32)
+    big = approx >= nf + jnp.float32(16.0)  # q > n whatever the error
+    q = jnp.floor(jnp.minimum(approx, nf + jnp.float32(16.0))).astype(i32)
+    # remainder of x·2²⁴ − q·den, exact mod 2³² and |rem| < 2³¹
+    rem = jax.lax.bitcast_convert_type(
+        (x.astype(_U) << _U(24)) - q.astype(_U) * den.astype(_U), i32)
+    t = jnp.floor(rem.astype(jnp.float32) / den.astype(jnp.float32)).astype(i32)
+    q, rem = q + t, rem - t * den
+    low = rem < 0
+    q, rem = jnp.where(low, q - 1, q), jnp.where(low, rem + den, rem)
+    high = rem >= den
+    q, rem = jnp.where(high, q + 1, q), jnp.where(high, rem - den, rem)
+    # q = ⌊x·2²⁴/den⌋ ≥ 1, rem ∈ [0, den).  fl32 rounds up to q+1 iff the
+    # gap (den − rem)/den is below half an ulp, 2^(e−24) for q ∈ [2^e, 2^(e+1)).
+    # It is never exactly half an ulp: a tie needs x·2^(48−e) = m·den with m
+    # odd, so 2^(v₂(x) + 48 − e) divides den: den ≥ 2²⁵ for e ≤ 23.  No
+    # tie-breaking rule is needed (q ≥ 2²⁴ ≥ n is clamped anyway).
+    e = (jax.lax.bitcast_convert_type(
+        jnp.maximum(q, 1).astype(jnp.float32), i32) >> 23) - 127
+    up = den - rem <= ((den - 1) >> jnp.clip(24 - e, 1, 24))
+    return jnp.where(big, n, jnp.minimum(q + up.astype(i32), n))
+
+
 def jump32(keys, n):
-    """Vectorized TPU-native JumpHash: keys uint32 [...], n a dynamic scalar.
+    """Vectorized TPU-native JumpHash: keys uint32 [...], n a dynamic scalar
+    (n ≤ 2²⁴, where every float32 step of the host reference is exact).
 
     State machine identical to the 64-bit original: ``b ← j; j ← ⌊(b+1)/r⌋``
-    with ``r`` uniform in (0, 1], iterated while ``j < n``; lane-synchronous
-    (a block settles in max-over-lanes steps, E ≈ ln n).
+    with ``r = (u+1)·2⁻²⁴`` uniform in (0, 1], iterated while ``j < n``;
+    lane-synchronous (a block settles in max-over-lanes steps, E ≈ ln n).
+    Each step equals the host's float32 step bit for bit on any backend
+    (:func:`floor_rn_quotient`).
+
+    Step 0 (every lane active at ``b = 0``) is peeled out of the loop, so
+    the carry ``j`` enters it already lane-varying: Mosaic cannot lay out
+    a loop carry that starts as a splat constant and is then updated from
+    the keys.
     """
     keys = jnp.asarray(keys).astype(_U)
-    nf = jnp.asarray(n).astype(jnp.float32)
-    b0 = jnp.zeros(keys.shape, jnp.int32)
-    j0 = jnp.zeros(keys.shape, jnp.float32)
+    n = jnp.asarray(n).astype(jnp.int32)
+
+    def step(b, i):
+        # u < 2^24 fits int32, and Mosaic casts only signed ints to float
+        den = step_u24(keys, i).astype(jnp.int32) + 1
+        x = b + 1
+        approx = x.astype(jnp.float32) / (den.astype(jnp.float32)
+                                          * jnp.float32(2.0 ** -24))
+        return floor_rn_quotient(x, den, approx, n)
 
     def cond(state):
         _, j, _ = state
-        return jnp.any(j < nf)
+        return jnp.any(j < n)
 
     def body(state):
         b, j, i = state
-        active = j < nf
-        b = jnp.where(active, j.astype(jnp.int32), b)
-        u = step_u24(keys, i)
-        r = (u.astype(jnp.float32) + jnp.float32(1.0)) * jnp.float32(2.0 ** -24)
-        jn = jnp.minimum(jnp.floor((b.astype(jnp.float32) + jnp.float32(1.0)) / r), nf)
-        j = jnp.where(active, jn, j)
-        return b, j, i + jnp.int32(1)
+        active = j < n
+        b = jnp.where(active, j, b)
+        return b, jnp.where(active, step(b, i), j), i + jnp.int32(1)
 
-    b, _, _ = jax.lax.while_loop(cond, body, (b0, j0, jnp.int32(0)))
+    b0 = jnp.zeros_like(keys, jnp.int32)
+    b, _, _ = jax.lax.while_loop(cond, body,
+                                 (b0, step(b0, jnp.int32(0)), jnp.int32(1)))
     return b
 
 
@@ -99,7 +153,7 @@ def power32(keys, n):
     hi_mask = (_U(1) << (L + 1).astype(_U)) - _U(1)
     base = _U(POWER_SALT) + (L.astype(_U) << _U(6))
     v0 = hash2(keys, base) & hi_mask
-    t0 = jnp.ones(keys.shape, jnp.int32)
+    t0 = jnp.ones_like(keys, jnp.int32)
 
     def rcond(state):
         v, t = state
@@ -132,7 +186,8 @@ def power32(keys, n):
 
 
 def gather1d(table, idx):
-    """Row gather of a flat VMEM table by a 2-D (or any-D) index block."""
+    """Row gather of a flat table by a 2-D (or any-D) index block (jnp and
+    interpret-mode Pallas only: Mosaic supports 2-D gathers alone)."""
     return jnp.take(table, idx.reshape(-1), axis=0).reshape(idx.shape)
 
 

@@ -121,8 +121,6 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str, *,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     from repro.launch.hlo_analysis import analyze
     hla = analyze(hlo)

@@ -12,12 +12,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    # jax.sharding.AxisType only exists on newer jax; older releases default
-    # every axis to Auto anyway, so omit the kwarg when it's unavailable.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,10 +39,7 @@ def init_distributed(coordinator_address: str, num_processes: int,
     initializes the backend (a no-op on TPU, where ICI collectives are
     native).
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older/newer jax without the option: TPU paths don't need it
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

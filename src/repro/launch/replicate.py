@@ -497,8 +497,8 @@ class FollowerImageStore:
         self.compact = compact
         self._registry = registry  # None → follow the process default
         if interpret is None:
-            import jax
-            interpret = jax.default_backend() != "tpu"
+            from repro.kernels.engine import default_interpret
+            interpret = default_interpret()
         self._interpret = interpret
         self._front: DeviceImage | None = None
         self.frames_applied = 0

@@ -2,17 +2,17 @@
 
     t_compute    = HLO_FLOPs_per_dev / peak_flops      (bf16 MXU / FMA peak)
     t_memory     = HLO_bytes_per_dev / mem_bw          (HBM / DRAM bandwidth)
-    t_collective = collective_bytes_per_dev / link_bw  (per-link ICI / NVLink)
+    t_collective = collective_bytes_per_dev / link_bw  (chip-to-chip interconnect)
 
 `MODEL_FLOPS` = 6·N_active·D for training (N = active params, D = tokens) or
 2·N_active·D for serving; the ratio against total HLO FLOPs exposes
 remat/padding/dispatch waste (brief §Roofline).
 
 The constants live in :data:`HARDWARE`, keyed by a spec name; the process
-default comes from :func:`detect_hardware` (the jax backend + device kind)
-and can be forced with ``REPRO_ROOFLINE_HW=<spec name>`` — numbers computed
-against the wrong machine's roofline are silently wrong, so every consumer
-reports the spec name it used alongside its utilizations.
+default comes from :func:`detect_hardware` (the device's ``device_kind``
+through :data:`DEVICE_KINDS`; an unknown kind is an error) and can be
+forced with ``REPRO_ROOFLINE_HW=<spec name>``.  Every consumer reports the
+spec name it used alongside its utilizations.
 """
 from __future__ import annotations
 
@@ -24,26 +24,31 @@ HARDWARE_ENV = "REPRO_ROOFLINE_HW"
 
 @dataclass(frozen=True)
 class HardwareSpec:
-    """Peak rates of one accelerator (per chip / per link)."""
+    """Peak rates of one accelerator (per chip)."""
 
     name: str
     peak_flops: float    # FLOP/s per chip (bf16 where the chip has an MXU)
     mem_bw: float        # bytes/s per chip (HBM / DRAM)
-    link_bw: float       # bytes/s per inter-chip link (ICI / NVLink / PCIe)
+    link_bw: float       # bytes/s of chip-to-chip interconnect per chip
 
 
-#: spec name → peaks.  TPU numbers are per-chip bf16 + HBM + per-link ICI;
-#: GPU numbers are per-GPU bf16 tensor-core + HBM + per-direction NVLink;
-#: ``cpu-host`` is a deliberately round server-class placeholder (FMA peak,
-#: DDR bandwidth, PCIe link) so off-TPU runs label utilizations against an
-#: honest denominator instead of a v5e they are not running on.
+#: spec name → peaks.  ``tpu-v5e``: one TPU v5e chip, 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s (200 GB/s) inter-chip interconnect (Google
+#: Cloud documentation, "TPU v5e").  ``cpu-host`` is a deliberately round
+#: server-class placeholder (FMA peak, DDR bandwidth, PCIe link) so off-TPU
+#: runs label utilizations against an honest denominator instead of a
+#: chip they are not running on.
 HARDWARE: dict[str, HardwareSpec] = {
-    "tpu-v5e":  HardwareSpec("tpu-v5e",  197e12, 819e9, 50e9),
-    "tpu-v4":   HardwareSpec("tpu-v4",   275e12, 1228e9, 50e9),
-    "tpu-v5p":  HardwareSpec("tpu-v5p",  459e12, 2765e9, 100e9),
-    "gpu-a100": HardwareSpec("gpu-a100", 312e12, 2039e9, 300e9),
-    "gpu-h100": HardwareSpec("gpu-h100", 989e12, 3350e9, 450e9),
+    "tpu-v5e":  HardwareSpec("tpu-v5e",  197e12, 819e9, 1600e9 / 8),
     "cpu-host": HardwareSpec("cpu-host", 1e12,   100e9,  32e9),
+}
+
+#: ``jax.Device.device_kind`` → :data:`HARDWARE` spec name.  A kind that is
+#: not listed has no peaks here: :func:`detect_hardware` refuses it rather
+#: than borrow another chip's.
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+    "cpu": "cpu-host",
 }
 
 # legacy module constants (v5e): kept for the dry-run launch path, which
@@ -54,12 +59,12 @@ ICI_BW = HARDWARE["tpu-v5e"].link_bw
 
 
 def detect_hardware() -> str:
-    """Map the live jax backend to a :data:`HARDWARE` spec name.
+    """Map the live jax device to a :data:`HARDWARE` spec name by its
+    ``device_kind`` (:data:`DEVICE_KINDS`).
 
-    ``REPRO_ROOFLINE_HW`` overrides detection (it must name a known spec);
-    unknown device kinds fall back to the family default (v5e for TPU,
-    a100 for GPU) — the spec *name* travels with every record, so a
-    fallback is visible, never silent.
+    ``REPRO_ROOFLINE_HW`` overrides detection (it must name a known spec).
+    A device kind without an entry raises: numbers computed against the
+    wrong machine's peaks are silently wrong.
     """
     forced = os.environ.get(HARDWARE_ENV)
     if forced:
@@ -69,18 +74,12 @@ def detect_hardware() -> str:
         return forced
     import jax
 
-    backend = jax.default_backend()
-    if backend == "cpu":
-        return "cpu-host"
-    kind = jax.devices()[0].device_kind.lower()
-    if backend == "tpu":
-        for name in ("tpu-v5p", "tpu-v5e", "tpu-v4"):
-            if name.split("-")[1] in kind:
-                return name
-        return "tpu-v5e"
-    if backend == "gpu":
-        return "gpu-h100" if "h100" in kind else "gpu-a100"
-    return "cpu-host"
+    dev = jax.devices()[0]
+    name = DEVICE_KINDS.get(dev.device_kind)
+    if name is None:
+        raise ValueError(f"no roofline peaks for {dev.platform} device kind "
+                         f"{dev.device_kind!r} (known: {sorted(DEVICE_KINDS)})")
+    return name
 
 
 def hardware_spec(name: str | None = None) -> HardwareSpec:

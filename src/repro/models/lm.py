@@ -14,8 +14,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.rules import shard_map
-
 from . import attention, mlp, rglru, ssm
 from .common import PSpec, init_tree, rms_norm, shape_tree, spec_tree, stack
 
@@ -226,7 +224,7 @@ class LM:
             rows = jnp.where(ok[..., None], rows, jnp.zeros((), tab.dtype))
             return jax.lax.psum(rows, vax)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=rules.mesh,
             in_specs=(P(vax, None), P(bspec, None)),
             out_specs=P(bspec, None, None))(table, tokens)

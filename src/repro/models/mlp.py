@@ -4,8 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.rules import shard_map
-
 from .common import ACTIVATIONS, PSpec
 
 
@@ -202,7 +200,7 @@ def _moe_apply_local(cfg, p, x, rules):
 
     espec = ep if ep_axes else None
     wg = p.get("wg", p["wi"])  # dummy when ungated (ignored in body)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P(espec, None, None), P(espec, None, None), P(espec, None, None)),

@@ -59,7 +59,7 @@ class ShardedLookupPlane:
                  k: int = 1, plane: str = "jnp", interpret: bool | None = None,
                  block_rows: int | None = None, sync_mode: str = "block",
                  registry=None):
-        import jax
+        from repro.kernels.engine import default_interpret
 
         if plane not in ("jnp", "pallas", "auto"):
             raise ValueError(f"unknown plane {plane!r}")
@@ -75,8 +75,8 @@ class ShardedLookupPlane:
         self.axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
         self.k = k
         self.plane = plane
-        self._interpret = (jax.default_backend() != "tpu"
-                           if interpret is None else interpret)
+        self._interpret = (default_interpret() if interpret is None
+                           else interpret)
         self._block_rows = block_rows
         self._source = source
         self._registry = registry  # None → follow the process default
@@ -168,19 +168,11 @@ class ShardedLookupPlane:
         fn = self._fns.get(key)
         if fn is not None:
             return fn
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from repro.core.jax_lookup import lookup_dispatch
         from repro.kernels import autotune
-        from repro.kernels.engine import (EngineOp, _engine_pallas, _pad_rows,
-                                          _tables2d, algo_body, replica_body)
-        from repro.sharding.rules import shard_map
+        from repro.kernels.engine import EngineOp
 
         op = EngineOp(algo=self._image.algo, k=self.k,
                       table="packed" if packed else "dense")
-        names = op.table_names
         # tuned parameters resolve once, at program-build time, against the
         # per-shard batch this program will always see (padded is part of
         # the fn cache key, so the resolution is as static as the jit key).
@@ -191,40 +183,9 @@ class ShardedLookupPlane:
             plane = autotune.resolve_plane(op, shard_keys, table_n)
         block_rows = (self._block_rows if self._block_rows is not None
                       else autotune.resolve_block_rows(op, shard_keys, table_n))
-        shard_dim = self.axes if len(self.axes) > 1 else self.axes[0]
-        key_spec = P(shard_dim)
-
-        def per_shard(keys, arrays, scalars):
-            # keys travel as an int32 buffer so the k=1 result (int32, same
-            # shape) can alias the donated input; bitcast restores uint32.
-            keys = jax.lax.bitcast_convert_type(keys, jnp.uint32)
-            if plane == "jnp":
-                if packed:
-                    body = lambda kk: algo_body(op, kk,
-                                                [arrays[n] for n in names],
-                                                list(scalars))
-                else:
-                    body = lambda kk: lookup_dispatch(op.algo, kk, arrays,
-                                                      scalars)
-                outs = replica_body(keys, op.k, body)
-            else:  # one Pallas launch per shard, tables in VMEM
-                keys2d, nk = _pad_rows(keys)
-                tabs = tuple(_tables2d([arrays[n] for n in names]))
-                scal = (jnp.stack(scalars) if scalars
-                        else jnp.zeros((0,), jnp.int32))
-                raw = _engine_pallas(
-                    scal, (keys2d,), tabs, op=op,
-                    block_rows=block_rows,
-                    interpret=self._interpret)
-                outs = [o.reshape(-1)[:nk] for o in raw]
-            return outs[0] if op.k == 1 else jnp.stack(outs)  # [K'] | [k, K']
-
-        f = shard_map(per_shard, mesh=self.mesh,
-                      in_specs=(key_spec, P(), P()),
-                      out_specs=key_spec if op.k == 1 else P(None, shard_dim))
-        # k=1: the int32 result aliases the donated int32 key buffer —
-        # steady-state streaming keeps two buffers alive, not 2×batches.
-        fn = jax.jit(f, donate_argnums=(0,) if op.k == 1 else ())
+        fn = sharded_lookup_program(op, self.mesh, self.axes, plane=plane,
+                                    block_rows=block_rows,
+                                    interpret=self._interpret)
         self._fns[key] = fn
         return fn
 
@@ -249,15 +210,23 @@ class ShardedLookupPlane:
         """Sharded batched lookup: keys [K] → np int32 [K] (k=1) or [K, k]."""
         reg = self._obs()
         t0 = time.perf_counter_ns() if reg.active else 0
+        out, n = self.lookup_async(keys)
+        res = self._finish(out, n)
+        if reg.active:
+            self._record_batch(reg, n, out.shape[-1], t0)
+        return res
+
+    def lookup_async(self, keys):
+        """Dispatch one batch without waiting: returns the still-sharded
+        device result (int32 ``[padded]``, or ``[k, padded]``, split over
+        the mesh) and the number of real keys at its front.  Picks up any
+        epoch flip first, like every batch."""
+        # obs-exempt: lookup/route_stream record the batch
         self._poll_source()
         self._ensure()
         dev, n, padded = self._stage(keys)
         arrays, scalars = self._dev
-        out = self._sharded_fn(padded)(dev, arrays, scalars)
-        res = self._finish(out, n)
-        if reg.active:
-            self._record_batch(reg, n, padded, t0)
-        return res
+        return self._sharded_fn(padded)(dev, arrays, scalars), n
 
     def route_stream(self, batches):
         """Stream key batches through the plane with double buffering.
@@ -270,13 +239,9 @@ class ShardedLookupPlane:
         pending = None  # (device out, n)
         for batch in batches:
             t0 = time.perf_counter_ns() if reg.active else 0
-            self._poll_source()  # overlap: commit a ready async epoch
-            self._ensure()  # pick up any epoch flip between batches
-            arrays, scalars = self._dev
-            dev, n, padded = self._stage(batch)
-            out = self._sharded_fn(padded)(dev, arrays, scalars)  # async
+            out, n = self.lookup_async(batch)
             if reg.active:  # dispatch latency — materialization overlaps
-                self._record_batch(reg, n, padded, t0)
+                self._record_batch(reg, n, out.shape[-1], t0)
             if pending is not None:
                 yield self._finish(*pending)
             pending = (out, n)
@@ -295,3 +260,54 @@ class ShardedLookupPlane:
     def _finish(self, out, n) -> np.ndarray:
         out = np.asarray(out)
         return out[:n] if self.k == 1 else out[:, :n].T
+
+
+def sharded_lookup_program(op, mesh, axes: tuple[str, ...], *, plane: str,
+                           block_rows: int, interpret: bool):
+    """The jitted ``shard_map`` program of one :class:`EngineOp`: int32 key
+    buffer sharded over ``axes`` (donated for k=1), image tables as a
+    name → array dict and layout scalars as a tuple, both replicated.
+    ``plane`` is resolved ("jnp" or "pallas")."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.jax_lookup import lookup_dispatch
+    from repro.kernels.engine import (_engine_pallas, _pad_rows, _tables2d,
+                                      algo_body, replica_body)
+
+    names = op.table_names
+    shard_dim = axes if len(axes) > 1 else axes[0]
+    key_spec = P(shard_dim)
+
+    def per_shard(keys, arrays, scalars):
+        # keys travel as an int32 buffer so the k=1 result (int32, same
+        # shape) can alias the donated input; bitcast restores uint32.
+        keys = jax.lax.bitcast_convert_type(keys, jnp.uint32)
+        if plane == "jnp":
+            if op.table == "packed":
+                body = lambda kk: algo_body(op, kk,
+                                            [arrays[n] for n in names],
+                                            list(scalars))
+            else:
+                body = lambda kk: lookup_dispatch(op.algo, kk,
+                                                  arrays, scalars)
+            outs = replica_body(keys, op.k, body)
+        else:  # one Pallas launch per shard, tables in VMEM
+            keys2d, nk = _pad_rows(keys)
+            tabs = tuple(_tables2d([arrays[n] for n in names]))
+            scal = (jnp.stack(scalars) if scalars
+                    else jnp.zeros((0,), jnp.int32))
+            raw = _engine_pallas(scal, (keys2d,), tabs, op=op,
+                                 block_rows=block_rows, interpret=interpret)
+            outs = [o.reshape(-1)[:nk] for o in raw]
+        return outs[0] if op.k == 1 else jnp.stack(outs)  # [K'] | [k, K']
+
+    # Pallas kernel bodies carry no varying-axis types, so only the jnp
+    # body is checked (its loop carries start from the keys, DESIGN.md §6)
+    f = jax.shard_map(per_shard, mesh=mesh, in_specs=(key_spec, P(), P()),
+                      out_specs=key_spec if op.k == 1 else P(None, shard_dim),
+                      check_vma=plane == "jnp")
+    # k=1: the int32 result aliases the donated int32 key buffer —
+    # steady-state streaming keeps two buffers alive, not 2×batches.
+    return jax.jit(f, donate_argnums=(0,) if op.k == 1 else ())

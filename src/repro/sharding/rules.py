@@ -26,36 +26,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """Version-portable shard_map.
-
-    Newer jax exposes ``jax.shard_map`` (partial-manual via ``axis_names`` =
-    the manual axes); older releases only have the experimental API, where
-    partial-manual is the complement (``auto`` = the non-manual axes).
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    # check_rep is a static replication checker with no numeric effect; the
-    # old one lacks rules for several collectives, so disable it.
-    kw = {"check_rep": False}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def pvary(x, axes):
-    """``jax.lax.pcast(x, axes, to="varying")`` where available.
-
-    On older jax (no varying-axis typing) the cast is a no-op numerically,
-    so identity is the correct fallback.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    return x if pcast is None else pcast(x, axes, to="varying")
-
-
 @dataclass(frozen=True)
 class AxisRules:
     rules: dict[str, tuple[str, ...]] = field(default_factory=dict)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.lm import block_apply
-from repro.sharding.rules import pvary, shard_map
 
 
 def make_pipelined_forward(model, rules, num_microbatches: int):
@@ -69,8 +68,10 @@ def make_pipelined_forward(model, rules, num_microbatches: int):
             handed = jax.lax.ppermute(h_out, "pod", perm)
             return (handed, outputs), None
 
-        inflight0 = pvary(jnp.zeros_like(embeds[0]), ("pod",))
-        outputs0 = pvary(jnp.zeros_like(embeds), ("pod",))
+        inflight0 = jax.lax.pcast(jnp.zeros_like(embeds[0]), ("pod",),
+                                  to="varying")
+        outputs0 = jax.lax.pcast(jnp.zeros_like(embeds), ("pod",),
+                                 to="varying")
         (_, outputs), _ = jax.lax.scan(
             tick, (inflight0, outputs0),
             jnp.arange(M + n_stages - 1, dtype=jnp.int32))
@@ -86,7 +87,7 @@ def make_pipelined_forward(model, rules, num_microbatches: int):
         embs = embeds.reshape(M, B // M, S, D)
         # partial-manual shard_map: only the pod axis is manual; data/model
         # sharding rides on the arrays themselves under GSPMD.
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("pod"), P()),
             out_specs=P(),

@@ -16,10 +16,43 @@ def keys():
 def test_jnp_jump_matches_numpy(keys):
     import jax.numpy as jnp
 
-    for n in (1, 3, 97, 4096, 100000):
+    for n in (1, 3, 97, 4096, 100000, 1_000_000, 1 << 24):
         dev = np.asarray(jax_lookup.jump32(jnp.asarray(keys), n))
         host = np_jump32(keys, n)
         np.testing.assert_array_equal(dev, host)
+
+
+@pytest.mark.parametrize("ulps", [-6, -2, -1, 0, 1, 2, 6])
+def test_floor_rn_quotient_corrects_an_inexact_divide(ulps):
+    """The jump step equals the host's correctly rounded float32 step even
+    when the device's divide is off by a few ulps (a TPU's is)."""
+    import jax.numpy as jnp
+
+    from repro.kernels.primitives import floor_rn_quotient
+
+    rng = np.random.default_rng(ulps + 100)
+    # quotients around 2²³..2²⁴ (ulp ≥ 1), some beyond the clamp n = 2²⁴
+    den = rng.integers(1, (1 << 24) + 1, size=1 << 13)
+    q = rng.integers(1 << 22, (1 << 24) + (1 << 22), size=den.size)
+    x = np.clip(q * den >> 24, 1, 1 << 24)
+    # and quotients whose fraction sits at the round-up boundary of float32:
+    # the gap to the next integer within one unit of den/2^(24−e)
+    bden = rng.integers(2, 1 << 12, size=1 << 21)
+    bx = rng.integers(1, bden)
+    bq, brem = np.divmod(bx << 24, bden)
+    sh = np.clip(24 - np.floor(np.log2(bq)).astype(np.int64), 1, 24)
+    near = np.abs(bden - brem - ((bden - 1) >> sh)) <= 1
+    x = np.concatenate([x, bx[near]]).astype(np.int32)
+    den = np.concatenate([den, bden[near]]).astype(np.int32)
+    n = np.int32(1 << 24)
+    exact = np.float32(x) / (np.float32(den) * np.float32(2.0 ** -24))
+    want = np.minimum(np.floor(exact), np.float32(n)).astype(np.int32)
+    approx = exact.copy()
+    for _ in range(abs(ulps)):
+        approx = np.nextafter(approx, np.float32(np.inf if ulps > 0 else 0))
+    got = floor_rn_quotient(jnp.asarray(x), jnp.asarray(den),
+                            jnp.asarray(approx), jnp.asarray(n))
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @pytest.mark.parametrize("n0,removals", [(16, 0), (16, 7), (128, 50), (1000, 400)])

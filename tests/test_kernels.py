@@ -5,8 +5,8 @@ import numpy as np
 import pytest
 
 from repro.core import MementoTables, random_state
-from repro.kernels import ops
 from repro.kernels import ref
+from repro.kernels.engine import engine_lookup
 
 
 def _state(n0, removals, seed=0):
@@ -21,7 +21,7 @@ def test_dense_kernel_matches_oracle(n0, removals, nkeys):
 
     m, tabs = _state(n0, removals, seed=n0 + nkeys)
     keys = np.random.default_rng(1).integers(0, 2**32, size=nkeys, dtype=np.uint32)
-    got = np.asarray(ops.memento_lookup(keys, tabs.repl, m.n, table="dense"))
+    got = np.asarray(engine_lookup(keys, m.device_image(), plane="pallas"))
     want = np.asarray(ref.memento_lookup_ref(jnp.asarray(keys), jnp.asarray(tabs.repl), m.n))
     np.testing.assert_array_equal(got, want)
     # and against the scalar host plane (end-to-end, three implementations)
@@ -34,7 +34,8 @@ def test_compact_kernel_matches_oracle(n0, removals):
 
     m, tabs = _state(n0, removals, seed=7)
     keys = np.random.default_rng(2).integers(0, 2**32, size=777, dtype=np.uint32)
-    got = np.asarray(ops.memento_lookup(keys, tabs.repl, m.n, table="compact"))
+    got = np.asarray(engine_lookup(keys, m.device_image(), plane="pallas",
+                                   table="compact"))
     want = np.asarray(ref.memento_lookup_ref(jnp.asarray(keys), jnp.asarray(tabs.repl), m.n))
     np.testing.assert_array_equal(got, want)
 
@@ -52,19 +53,18 @@ def test_compact_table_is_theta_r():
 def test_kernel_key_dtypes(dtype):
     m, tabs = _state(64, 20, seed=4)
     keys = np.random.default_rng(3).integers(0, 2**31, size=130).astype(dtype)
-    got = np.asarray(ops.memento_lookup(keys, tabs.repl, m.n))
+    got = np.asarray(engine_lookup(keys, m.device_image(), plane="pallas"))
     want = ref.memento_lookup_host(keys.astype(np.uint32), m)
     np.testing.assert_array_equal(got, want)
 
 
 def test_kernel_block_rows_sweep():
     import jax.numpy as jnp
-    from repro.kernels.engine import dense_lookup
 
     m, tabs = _state(512, 170, seed=5)
     keys = np.random.default_rng(4).integers(0, 2**32, size=2048, dtype=np.uint32)
     want = np.asarray(ref.memento_lookup_ref(jnp.asarray(keys), jnp.asarray(tabs.repl), m.n))
     for block_rows in (1, 2, 8, 16):
-        got = np.asarray(dense_lookup(jnp.asarray(keys), jnp.asarray(tabs.repl), m.n,
-                                      block_rows=block_rows))
+        got = np.asarray(engine_lookup(keys, m.device_image(), plane="pallas",
+                                       block_rows=block_rows))
         np.testing.assert_array_equal(got, want)

@@ -9,18 +9,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
-import pytest
-
 REPO = Path(__file__).resolve().parents[1]
-
-# Partial-manual shard_map with in-region sharding constraints that mention
-# the manual axis is only legal on newer jax (jax.shard_map + varying-axis
-# types); the old experimental API rejects it outright.
-requires_new_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="pipeline partial-manual shard_map requires jax.shard_map "
-           "(newer jax); the baked-in jax only has the experimental API")
 
 SCRIPT = r"""
 import os
@@ -68,7 +57,6 @@ print("PIPELINE_OK", err)
 """
 
 
-@requires_new_shard_map
 def test_pipeline_matches_forward():
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT],
