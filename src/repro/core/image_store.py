@@ -424,20 +424,24 @@ class DeviceImageStore:
         Compiles once per engine configuration and shape set; the store's
         stable padded capacities make every subsequent epoch a cache hit.
         ``k > 1`` returns [K, k] replica sets in the same single program.
-        Defaults to the store's configured apply plane.
+        Defaults to the store's configured apply plane.  Spans (each with
+        its ``.us`` histogram): ``store.lookup`` around the engine's
+        ``engine.dispatch`` and ``store.fetch``, the wait for the answer
+        and its copy to the host.
         """
         from repro.kernels.engine import engine_lookup
 
         plane = plane or self.plane
         reg = self._obs()
-        t0 = time.perf_counter_ns() if reg.active else 0
-        out = np.asarray(engine_lookup(keys, self._front, k=k, plane=plane,
-                                       **kw))
+        with reg.timed("store.lookup"):
+            dev = engine_lookup(keys, self._front, k=k, plane=plane,
+                                registry=reg, **kw)
+            with reg.timed("store.fetch"):
+                out = np.asarray(dev)
+                reg.flush_device()
         if reg.active:
             reg.counter("store.lookups").inc()
             reg.counter("store.lookup_keys").inc(int(out.shape[0]))
-            reg.histogram("store.lookup.us").observe(
-                (time.perf_counter_ns() - t0) / 1e3)
         return out
 
     def migration_diff(self, keys, *, plane: str = "jnp", k: int = 1, **kw):

@@ -34,13 +34,39 @@ def memento_lookup(keys, repl, n):
     Returns int32 bucket ids in [0, n) that are working buckets.
     """
     keys = jnp.asarray(keys).astype(_U)
+    return _memento_loops(keys, repl, n, (), lambda acc, work: ())[0]
+
+
+def memento_lookup_counted(keys, repl, n):
+    """:func:`memento_lookup` that also counts its loops' work.
+
+    Returns ``(buckets, sweeps, lane_sweeps)``: ``sweeps`` (int32) is the
+    number of iterations of the outer and inner loops together, each one
+    round of dependent ``repl`` gathers over the whole block;
+    ``lane_sweeps`` (uint32, wraps at 2³²) sums, over the lanes, the
+    iterations in which the lane did work (outer: still on a removed
+    bucket; inner: still following the chain).  Their ratio over the
+    block size is the share of each sweep spent on unsettled lanes.
+    """
+    keys = jnp.asarray(keys).astype(_U)
+    b, (sweeps, lanes) = _memento_loops(
+        keys, repl, n, (jnp.int32(0), jnp.zeros_like(keys)),
+        lambda acc, work: (acc[0] + 1, acc[1] + work.astype(_U)))
+    return b, sweeps, jnp.sum(lanes, dtype=_U)
+
+
+def _memento_loops(keys, repl, n, acc, tally):
+    """Alg. 4's two lane-synchronous loops; ``acc`` rides both loops'
+    carries and ``tally(acc, work)`` folds in each iteration's mask of
+    lanes with work (``()`` and a no-op carry nothing extra)."""
     b = jump32(keys, n)
 
     def outer_cond(state):
-        b = state
+        b, _ = state
         return jnp.any(repl[b] >= 0)
 
-    def outer_body(b):
+    def outer_body(state):
+        b, acc = state
         c = repl[b]
         active = c >= 0
         wb = jnp.where(active, c, 1)  # |W_b| (Prop. V.3); dummy 1 when settled
@@ -48,19 +74,21 @@ def memento_lookup(keys, repl, n):
         d = (h % wb.astype(_U)).astype(jnp.int32)
 
         def inner_cond(state):
-            d = state
+            d, _ = state
             u = repl[d]
             return jnp.any(active & (u >= 0) & (u >= wb))
 
-        def inner_body(d):
+        def inner_body(state):
+            d, acc = state
             u = repl[d]
             follow = active & (u >= 0) & (u >= wb)  # only while u ≥ w_b (balance)
-            return jnp.where(follow, u, d)
+            return jnp.where(follow, u, d), tally(acc, follow)
 
-        d = jax.lax.while_loop(inner_cond, inner_body, d)
-        return jnp.where(active, d, b)
+        d, acc = jax.lax.while_loop(inner_cond, inner_body,
+                                    (d, tally(acc, active)))
+        return jnp.where(active, d, b), acc
 
-    return jax.lax.while_loop(outer_cond, outer_body, b)
+    return jax.lax.while_loop(outer_cond, outer_body, (b, acc))
 
 
 def anchor_lookup(keys, A, K, a):

@@ -42,7 +42,6 @@ kernels ran, block padding included.
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass
 
 import jax
@@ -53,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bounded import accept_in_index_order, walk_probe_bound
 from repro.core.hashing import GOLDEN32
-from repro.core.jax_lookup import lookup_dispatch
+from repro.core.jax_lookup import lookup_dispatch, memento_lookup_counted
 from repro.core.packing import PACKED_LAYOUT, build_slots
 from repro.core.protocol import (ALGORITHMS, IMAGE_LAYOUT, REPLICA_SALT_CAP,
                                  image_scalar_vec)
@@ -97,18 +96,30 @@ def _resolve_block_rows(op, n_keys: int, table_n: int,
     return autotune.resolve_block_rows(op, n_keys, table_n)
 
 
-def _obs_dispatch(reg, op: EngineOp, n_keys: int, t0_ns: int) -> None:
-    """Fold one engine dispatch into the live telemetry registry
-    (DESIGN.md §11): dispatches served, keys, batch-size distribution, and
-    a per-:class:`EngineOp` latency histogram keyed by the autotuner's op
-    tag.  Counters are integers of replayed control flow, so a replay's
-    counter snapshot is bit-identical; only the latency buckets float."""
+def _dispatch_span(reg, op: "EngineOp"):
+    """The ``engine.dispatch`` span of one engine dispatch: operand
+    marshalling, the key upload and the launch.  It also observes the
+    per-:class:`EngineOp` latency histogram ``engine.dispatch.us``, keyed
+    by the autotuner's op tag."""
+    if not reg.active:
+        return reg.timed("engine.dispatch")
     from .autotune import op_tag
+    return reg.timed("engine.dispatch", labels={"op": op_tag(op)})
+
+
+def _obs_dispatch(reg, n_keys: int) -> None:
+    """Fold one engine dispatch into the live telemetry registry
+    (DESIGN.md §11): dispatches served, keys, and the batch-size
+    distribution.  Counters are integers of replayed control flow, so a
+    replay's counter snapshot is bit-identical."""
     reg.counter("engine.dispatches").inc()
     reg.counter("engine.keys").inc(n_keys)
     reg.histogram("engine.batch_keys").observe(n_keys)
-    reg.histogram("engine.dispatch.us", op=op_tag(op)).observe(
-        (time.perf_counter_ns() - t0_ns) / 1e3)
+
+
+#: the histograms the counted Memento program's two device counts feed
+#: (:func:`repro.core.jax_lookup.memento_lookup_counted`), one value a batch
+MEMENTO_SWEEP_HISTOGRAMS = ("engine.memento.sweeps", "engine.memento.lane_sweeps")
 
 
 # ---------------------------------------------------------------------------
@@ -563,7 +574,9 @@ def _engine_pallas(scalars, blocks2d, tables2d, *, op: EngineOp,
 
 # ---------------------------------------------------------------------------
 # jnp plane: one jitted program per configuration (traced operands, so one
-# compile serves every epoch of a given shape)
+# compile serves every epoch of a given shape).  Lookup mode returns
+# ``(outs, counts)``: the plain dense Memento lookup counts its loops' work
+# on the device, in the same program whether or not anyone reads it.
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("op",))
@@ -591,15 +604,26 @@ def _engine_jnp(blocks, arrays, scalars, load, cap, *, op: EngineOp):
         return replica_body(keys, op.k, dispatch(tabs, scals),
                             load=load if op.bounded else None, cap=cap)
 
-    outs = epoch_outs(tables[:nt], scalars[:op.num_scalars])
     if op.diff:
+        outs = epoch_outs(tables[:nt], scalars[:op.num_scalars])
         new = epoch_outs(tables[nt:2 * nt],
                          scalars[op.num_scalars:2 * op.num_scalars])
         moved = jnp.zeros(keys.shape, jnp.bool_)
         for o, n_ in zip(outs, new):
             moved = moved | (o != n_)
         return tuple(outs), tuple(new), moved
-    return tuple(outs)
+    if _counts_sweeps(op):
+        b, sweeps, lanes = memento_lookup_counted(keys, tables[0], scalars[0])
+        return (b,), jnp.stack([sweeps.astype(_U), lanes])
+    return tuple(epoch_outs(tables[:nt], scalars[:op.num_scalars])), None
+
+
+def _counts_sweeps(op: EngineOp) -> bool:
+    """Does the jnp program of ``op`` return the Memento loop counts
+    (:data:`MEMENTO_SWEEP_HISTOGRAMS`) beside its buckets?  The plain dense
+    Memento lookup does; every other configuration returns ``None``."""
+    return (op.algo == "memento" and op.table == "dense" and op.k == 1
+            and op.mode == "lookup" and not op.bounded and not op.diff)
 
 
 # ---------------------------------------------------------------------------
@@ -657,50 +681,55 @@ def _jnp_operands(images):
 def engine_lookup(keys, image, *, k: int = 1, load=None, cap: int | None = None,
                   plane: str = "pallas", table: str = "dense",
                   interpret: bool | None = None,
-                  block_rows: int | None = None):
+                  block_rows: int | None = None, registry=None):
     """The one batched lookup: keys [K] → int32 [K] (k=1) or [K, k].
 
     ``k>1`` returns salted k-replica sets (column 0 = the plain lookup);
     passing ``load``/``cap`` fuses the bounded-load rejection into the same
     single launch (every returned bucket has ``load < cap``, slot 0
     included).  Bit-identical to the host plane on ``variant="32"`` states.
+    Telemetry lands on ``registry``, else on the process default; the
+    Memento loop counts are queued on it (``observe_device``) for the
+    caller to flush once it has fetched the result.
     """
     bounded = load is not None
     if bounded and cap is None:
         raise ValueError("bounded lookup needs a cap")
     table = _op_table(image, table)
     op = EngineOp(algo=image.algo, k=k, bounded=bounded, table=table)
-    keys = jnp.asarray(keys, dtype=_U)
-    _reg = _obs_registry()
-    _t0 = time.perf_counter_ns() if _reg.active else 0
-    if plane == "jnp":
-        if table == "compact":
-            raise ValueError("jnp plane serves the dense layout")
-        arrays, scalars = _jnp_operands([image])
-        outs = _engine_jnp((keys,), arrays, scalars,
-                           None if load is None else jnp.asarray(load, jnp.int32),
-                           None if cap is None else jnp.asarray(cap, jnp.int32),
-                           op=op)
-        out = outs[0] if k == 1 else jnp.stack(outs).T
-    elif plane != "pallas":
-        raise ValueError(f"unknown plane {plane!r}")
-    else:
-        if interpret is None:
-            interpret = default_interpret()
-        tables = _image_tables(op, image)
-        if bounded:
-            tables.append(jnp.asarray(load, jnp.int32))
-        keys2d, nk = _pad_rows(keys)
-        outs = _engine_pallas(_scalar_vec(op, [image], cap), (keys2d,),
-                              tuple(_tables2d(tables)), op=op,
-                              block_rows=_resolve_block_rows(
-                                  op, nk, int(image.n), block_rows),
-                              interpret=interpret)
-        flat = [o.reshape(-1)[:nk] for o in outs]
-        out = flat[0] if k == 1 else jnp.stack(flat).T
-    if _reg.active:
-        _reg.counter("engine.lookups").inc()
-        _obs_dispatch(_reg, op, int(keys.shape[0]), _t0)
+    reg = registry if registry is not None else _obs_registry()
+    with _dispatch_span(reg, op):
+        keys = jnp.asarray(keys, dtype=_U)
+        if plane == "jnp":
+            if table == "compact":
+                raise ValueError("jnp plane serves the dense layout")
+            arrays, scalars = _jnp_operands([image])
+            outs, counts = _engine_jnp(
+                (keys,), arrays, scalars,
+                None if load is None else jnp.asarray(load, jnp.int32),
+                None if cap is None else jnp.asarray(cap, jnp.int32), op=op)
+            out = outs[0] if k == 1 else jnp.stack(outs).T
+            if counts is not None and reg.active:
+                reg.observe_device(MEMENTO_SWEEP_HISTOGRAMS, counts)
+        elif plane != "pallas":
+            raise ValueError(f"unknown plane {plane!r}")
+        else:
+            if interpret is None:
+                interpret = default_interpret()
+            tables = _image_tables(op, image)
+            if bounded:
+                tables.append(jnp.asarray(load, jnp.int32))
+            keys2d, nk = _pad_rows(keys)
+            outs = _engine_pallas(_scalar_vec(op, [image], cap), (keys2d,),
+                                  tuple(_tables2d(tables)), op=op,
+                                  block_rows=_resolve_block_rows(
+                                      op, nk, int(image.n), block_rows),
+                                  interpret=interpret)
+            flat = [o.reshape(-1)[:nk] for o in outs]
+            out = flat[0] if k == 1 else jnp.stack(flat).T
+    if reg.active:
+        reg.counter("engine.lookups").inc()
+        _obs_dispatch(reg, int(keys.shape[0]))
     if bounded:
         # Slots are only accepted when distinct AND below the cap, so an
         # over-cap bucket OR a duplicate row means that lane exhausted the
@@ -764,14 +793,14 @@ def engine_diff(keys, old_image, new_image, *, k: int = 1,
     if not reg.active:
         return _engine_diff(keys, old_image, new_image, k=k, plane=plane,
                             interpret=interpret, block_rows=block_rows)
-    t0 = time.perf_counter_ns()
-    out = _engine_diff(keys, old_image, new_image, k=k, plane=plane,
-                       interpret=interpret, block_rows=block_rows)
+    op = EngineOp(algo=new_image.algo, k=k, diff=True,
+                  table=_op_table(new_image))
+    with _dispatch_span(reg, op):
+        out = _engine_diff(keys, old_image, new_image, k=k, plane=plane,
+                           interpret=interpret, block_rows=block_rows)
     reg.counter("engine.diffs").inc()
     reg.counter("engine.moved_keys").inc(out.num_moved)
-    _obs_dispatch(reg, EngineOp(algo=new_image.algo, k=k, diff=True,
-                                table=_op_table(new_image)),
-                  int(np.shape(keys)[0]), t0)
+    _obs_dispatch(reg, int(np.shape(keys)[0]))
     return out
 
 
@@ -788,8 +817,8 @@ def _engine_diff(keys, old_image, new_image, *, k: int = 1,
                               table=_op_table(new_image))
             ao, so = _jnp_operands([old_image])
             an, sn = _jnp_operands([new_image])
-            old = _engine_jnp((keys,), ao, so, None, None, op=op_old)
-            new = _engine_jnp((keys,), an, sn, None, None, op=op_new)
+            old, _ = _engine_jnp((keys,), ao, so, None, None, op=op_old)
+            new, _ = _engine_jnp((keys,), an, sn, None, None, op=op_new)
             old_np = _stack_np(old, k)
             new_np = _stack_np(new, k)
             moved = (old_np != new_np) if k == 1 else \
@@ -843,38 +872,38 @@ def engine_chain_walk(chain, probe, pending, image, load, cap, *,
     of its rehash chain with ``load[b] < cap``.  Returns numpy
     ``(b, chain, probe)``; non-pending lanes come back unchanged."""
     op = EngineOp(algo=image.algo, mode="walk", table=_op_table(image))
-    _reg = _obs_registry()
-    _t0 = time.perf_counter_ns() if _reg.active else 0
-    chain = jnp.asarray(chain, dtype=_U)
-    probe = jnp.asarray(probe, dtype=jnp.int32)
-    pending = jnp.asarray(pending, dtype=jnp.bool_)
-    load = jnp.asarray(load, dtype=jnp.int32)
+    reg = _obs_registry()
+    with _dispatch_span(reg, op):
+        chain = jnp.asarray(chain, dtype=_U)
+        probe = jnp.asarray(probe, dtype=jnp.int32)
+        pending = jnp.asarray(pending, dtype=jnp.bool_)
+        load = jnp.asarray(load, dtype=jnp.int32)
+        nk = chain.shape[0]
+        if plane == "jnp":
+            arrays, scalars = _jnp_operands([image])
+            b, ch, pr = _engine_jnp((chain, probe, pending), arrays, scalars,
+                                    load, jnp.asarray(cap, jnp.int32), op=op)
+        elif plane != "pallas":
+            raise ValueError(f"unknown plane {plane!r}")
+        else:
+            if interpret is None:
+                interpret = default_interpret()
+            chain2d, _ = _pad_rows(chain)
+            probe2d, _ = _pad_rows(probe)
+            pending2d, _ = _pad_rows(pending.astype(jnp.int32))
+            tables = _image_tables(op, image) + [load]
+            b, ch, pr = _engine_pallas(
+                _scalar_vec(op, [image], cap), (chain2d, probe2d, pending2d),
+                tuple(_tables2d(tables)), op=op,
+                block_rows=_resolve_block_rows(op, nk, int(image.n),
+                                               block_rows),
+                interpret=interpret)
+    if reg.active:
+        reg.counter("engine.walk_steps").inc()
+        _obs_dispatch(reg, nk)
     if plane == "jnp":
-        arrays, scalars = _jnp_operands([image])
-        b, ch, pr = _engine_jnp((chain, probe, pending), arrays, scalars,
-                                load, jnp.asarray(cap, jnp.int32), op=op)
-        if _reg.active:
-            _reg.counter("engine.walk_steps").inc()
-            _obs_dispatch(_reg, op, int(chain.shape[0]), _t0)
         return (np.asarray(b), np.asarray(ch).astype(np.uint32),
                 np.asarray(pr))
-    if plane != "pallas":
-        raise ValueError(f"unknown plane {plane!r}")
-    if interpret is None:
-        interpret = default_interpret()
-    nk = chain.shape[0]
-    chain2d, _ = _pad_rows(chain)
-    probe2d, _ = _pad_rows(probe)
-    pending2d, _ = _pad_rows(pending.astype(jnp.int32))
-    tables = _image_tables(op, image) + [load]
-    b, ch, pr = _engine_pallas(
-        _scalar_vec(op, [image], cap), (chain2d, probe2d, pending2d),
-        tuple(_tables2d(tables)), op=op,
-        block_rows=_resolve_block_rows(op, nk, int(image.n), block_rows),
-        interpret=interpret)
-    if _reg.active:
-        _reg.counter("engine.walk_steps").inc()
-        _obs_dispatch(_reg, op, nk, _t0)
     take = lambda x: np.asarray(x.reshape(-1)[:nk])  # noqa: E731
     return take(b), take(ch).astype(np.uint32), take(pr)
 

@@ -8,7 +8,9 @@ One low-overhead subsystem threaded through every serving layer:
   :class:`NullRegistry` so disabled telemetry costs one attribute lookup;
 * :mod:`repro.obs.trace`   — nested ``span("sync.flip")`` tracing with
   monotonic stamps that also enters ``jax.profiler`` named scopes, so
-  wall-clock spans line up with XLA device traces;
+  wall-clock spans line up with XLA device traces, with a pairing onto
+  the profiler's host clock; ``registry.timed(name)`` is such a span that
+  also observes its ``<name>.us`` histogram;
 * :mod:`repro.obs.export`  — Prometheus-style text exposition plus a
   bounded JSONL :class:`TelemetrySink` benchmarks and CI snapshot
   deterministically.
@@ -25,14 +27,14 @@ from .export import (NullSink, TelemetrySink, render_prometheus,
                      snapshot_text)
 from .metrics import (Counter, Gauge, Histogram, MetricRegistry,
                       NullRegistry, bucket_index, bucket_upper,
-                      default_registry, disable, enable, ensure_real,
-                      set_default_registry)
+                      count_compiles, default_registry, disable, enable,
+                      ensure_real, set_default_registry)
 from .trace import NullTracer, Span, Tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "NullRegistry",
     "NullSink", "NullTracer", "Span", "TelemetrySink", "Tracer",
-    "bucket_index", "bucket_upper", "default_registry", "disable",
-    "enable", "ensure_real", "render_prometheus", "set_default_registry",
-    "snapshot_text",
+    "bucket_index", "bucket_upper", "count_compiles", "default_registry",
+    "disable", "enable", "ensure_real", "render_prometheus",
+    "set_default_registry", "snapshot_text",
 ]

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import math
 import threading
+import weakref
 
 #: log-bucket resolution: 4 buckets per power of two (factor 2^0.25).
 BUCKETS_PER_OCTAVE = 4
@@ -198,12 +199,16 @@ class MetricRegistry:
 
     active = True
 
+    #: device values queued before :meth:`flush_device` reads them anyway
+    MAX_QUEUED_DEVICE = 64
+
     def __init__(self, *, max_spans: int = 4096, max_events: int = 8192):
         from .export import TelemetrySink
         from .trace import Tracer
 
         self._lock = threading.Lock()
         self._metrics: dict[str, Counter | Gauge | Histogram] = {}
+        self._device: list[tuple[tuple[str, ...], object]] = []
         self.sink = TelemetrySink(max_events=max_events)
         self.tracer = Tracer(max_spans=max_spans, sink=self.sink)
 
@@ -233,6 +238,41 @@ class MetricRegistry:
         """Open a trace span on this registry's tracer (obs/trace.py)."""
         return self.tracer.span(name, **attrs)
 
+    def timed(self, name: str, labels: dict | None = None, **attrs):
+        """A span that also observes its duration into the ``<name>.us``
+        histogram (with ``labels``) when it closes: one clock for the
+        profiler timeline, the JSONL log and the histogram sums."""
+        return self.tracer.span(
+            name, hist=self.histogram(f"{name}.us", **(labels or {})), **attrs)
+
+    def next_batch(self) -> int:
+        """A fresh ``batch`` span attribute (obs/trace.py)."""
+        return self.tracer.next_batch()
+
+    def observe_device(self, names: tuple[str, ...], values) -> None:
+        """Queue a device array holding one value per name in ``names``;
+        :meth:`flush_device` observes each into the histogram of its name.
+        Queueing lets a dispatch hand over counters computed on the device
+        without waiting for them: their copy to the host starts now, and
+        the caller flushes once it has fetched the result they came with."""
+        start_copy = getattr(values, "copy_to_host_async", None)
+        if start_copy is not None:
+            start_copy()
+        with self._lock:
+            self._device.append((names, values))
+            full = len(self._device) >= self.MAX_QUEUED_DEVICE
+        if full:
+            self.flush_device()
+
+    def flush_device(self) -> None:
+        """Fetch the queued device values and observe them (blocks until
+        their programs finish)."""
+        with self._lock:
+            queued, self._device = self._device, []
+        for names, values in queued:
+            for name, v in zip(names, values.tolist()):
+                self.histogram(name).observe(v)
+
     def metrics(self) -> dict:
         with self._lock:
             return dict(self._metrics)
@@ -244,6 +284,7 @@ class MetricRegistry:
         (one observation per timed event) while their bucket spread is
         wall-clock-dependent — the determinism gate compares the former
         and only requires the latter populated."""
+        self.flush_device()
         out = {"counters": {}, "gauges": {}, "histograms": {}}
         for key, m in sorted(self.metrics().items()):
             if isinstance(m, Counter):
@@ -329,6 +370,18 @@ class NullRegistry:
     def span(self, name: str, **attrs):
         return self.tracer.span(name, **attrs)
 
+    def timed(self, name: str, labels: dict | None = None, **attrs):
+        return self.tracer.span(name)
+
+    def next_batch(self) -> int:
+        return 0
+
+    def observe_device(self, names, values) -> None:
+        pass
+
+    def flush_device(self) -> None:
+        pass
+
     def metrics(self) -> dict:
         return {}
 
@@ -378,3 +431,41 @@ def ensure_real(registry=None) -> MetricRegistry:
     if registry is not None and getattr(registry, "active", False):
         return registry
     return MetricRegistry()
+
+
+#: the ``jax.monitoring`` event of one XLA backend compile: in jax 0.9.0
+#: once per program jax had not compiled in this process, around the
+#: compile or its load from the persistent compile cache
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_registries: weakref.WeakSet = weakref.WeakSet()
+_compile_lock = threading.Lock()
+_compile_listening = False
+
+
+def _on_duration_event(event: str, duration_secs: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    with _compile_lock:
+        registries = list(_compile_registries)
+    for reg in registries:
+        reg.histogram("device.compile.us").observe(duration_secs * 1e6)
+
+
+def count_compiles(registry) -> None:
+    """From now on, while ``registry`` lives, fold every XLA compile of the
+    process into its ``device.compile.us`` histogram (count = compiles,
+    sum = µs).  The histogram exists from this call, so a window without
+    a compile reads 0.  One ``jax.monitoring`` listener serves the whole
+    process and holds its registries weakly; an inactive registry is not
+    watched."""
+    global _compile_listening
+    if not registry.active:
+        return
+    registry.histogram("device.compile.us")
+    with _compile_lock:
+        _compile_registries.add(registry)
+        if not _compile_listening:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration_event)
+            _compile_listening = True

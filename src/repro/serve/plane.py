@@ -139,19 +139,21 @@ class ShardedLookupPlane:
         img = self._current_image()
         if self._dev is not None and img is self._image:
             return
-        self._obs().counter("plane.repins").inc()
-        rep = NamedSharding(self.mesh, P())
-        names = image_table_names(img)
-        arrays = {}
-        for n in names:
-            src = img.arrays[n]
-            cached = self._rep_cache.get(n)
-            if cached is None or cached[0] is not src:
-                self._rep_cache[n] = (src, jax.device_put(jnp.asarray(src),
-                                                          rep))
-            arrays[n] = self._rep_cache[n][1]
-        scalars = tuple(jax.device_put(jnp.asarray(s, jnp.int32), rep)
-                        for s in image_scalar_vec(img))
+        reg = self._obs()
+        reg.counter("plane.repins").inc()
+        with reg.timed("plane.repin", epoch=img.epoch):
+            rep = NamedSharding(self.mesh, P())
+            names = image_table_names(img)
+            arrays = {}
+            for n in names:
+                src = img.arrays[n]
+                cached = self._rep_cache.get(n)
+                if cached is None or cached[0] is not src:
+                    self._rep_cache[n] = (src, jax.device_put(
+                        jnp.asarray(src), rep))
+                arrays[n] = self._rep_cache[n][1]
+            scalars = tuple(jax.device_put(jnp.asarray(s, jnp.int32), rep)
+                            for s in image_scalar_vec(img))
         self._image = img
         self._dev = (arrays, scalars)
 
@@ -209,22 +211,25 @@ class ShardedLookupPlane:
     def lookup(self, keys) -> np.ndarray:
         """Sharded batched lookup: keys [K] → np int32 [K] (k=1) or [K, k]."""
         reg = self._obs()
+        batch = reg.next_batch()
         t0 = time.perf_counter_ns() if reg.active else 0
-        out, n = self.lookup_async(keys)
-        res = self._finish(out, n)
+        out, n = self.lookup_async(keys, batch=batch)
+        res = self._fetch(reg, out, n, batch)
         if reg.active:
             self._record_batch(reg, n, out.shape[-1], t0)
         return res
 
-    def lookup_async(self, keys):
+    def lookup_async(self, keys, *, batch: int = 0):
         """Dispatch one batch without waiting: returns the still-sharded
         device result (int32 ``[padded]``, or ``[k, padded]``, split over
         the mesh) and the number of real keys at its front.  Picks up any
-        epoch flip first, like every batch."""
+        epoch flip first, like every batch.  ``batch`` is the id its
+        ``plane.stage`` span carries."""
         # obs-exempt: lookup/route_stream record the batch
         self._poll_source()
         self._ensure()
-        dev, n, padded = self._stage(keys)
+        with self._obs().timed("plane.stage", batch=batch):
+            dev, n, padded = self._stage(keys)
         arrays, scalars = self._dev
         return self._sharded_fn(padded)(dev, arrays, scalars), n
 
@@ -233,20 +238,28 @@ class ShardedLookupPlane:
 
         Yields one np result per input batch, in order.  The donated key
         buffers and the one-batch pipeline keep host staging of batch
-        *i+1* overlapped with device compute of batch *i*.
+        *i+1* overlapped with device compute of batch *i*.  Spans (each
+        with its ``.us`` histogram): ``plane.repin`` on an epoch flip,
+        ``plane.stage`` and ``plane.fetch`` (the wait for a batch's answer
+        and its copy to the host), the last two with the batch's id.
         """
         reg = self._obs()
-        pending = None  # (device out, n)
-        for batch in batches:
+        pending = None  # (device out, n, batch id)
+        for keys in batches:
+            batch = reg.next_batch()
             t0 = time.perf_counter_ns() if reg.active else 0
-            out, n = self.lookup_async(batch)
+            out, n = self.lookup_async(keys, batch=batch)
             if reg.active:  # dispatch latency — materialization overlaps
                 self._record_batch(reg, n, out.shape[-1], t0)
             if pending is not None:
-                yield self._finish(*pending)
-            pending = (out, n)
+                yield self._fetch(reg, *pending)
+            pending = (out, n, batch)
         if pending is not None:
-            yield self._finish(*pending)
+            yield self._fetch(reg, *pending)
+
+    def _fetch(self, reg, out, n: int, batch: int) -> np.ndarray:
+        with reg.timed("plane.fetch", batch=batch):
+            return self._finish(out, n)
 
     def _record_batch(self, reg, n: int, padded: int, t0_ns: int) -> None:
         """Per-batch plane telemetry: batch/key counters, the per-shard

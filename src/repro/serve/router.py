@@ -32,9 +32,9 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core import ConsistentHash, DeviceImageStore, make_hash
-from repro.core.hashing import key_to_u32
+from repro.core.hashing import key_to_u32, np_key_to_u32
+from repro.obs.metrics import count_compiles, ensure_real
 from repro.obs.metrics import default_registry as _default_obs
-from repro.obs.metrics import ensure_real
 
 
 class RouterStats:
@@ -116,6 +116,8 @@ class SessionRouter:
         self.compact_images = compact_images
         self.block_rows = block_rows
         self._registry = registry  # None → follow the process default
+        if registry is not None:  # device.compile.us: compiles from now on
+            count_compiles(registry)
         # stats land on the injected registry when it records, else on the
         # process default, else on the view's own private registry — the
         # public counter API works with telemetry globally off.
@@ -210,28 +212,25 @@ class SessionRouter:
         return sets[np.arange(len(sets)), col]
 
     def route_batch(self, session_ids: np.ndarray) -> np.ndarray:
-        from repro.core.hashing import np_key_to_u32
+        """Session ids → np int32 replicas, in one engine launch.  Spans
+        (each with its ``.us`` histogram, all with one ``batch`` id):
+        ``router.route_batch`` around ``router.hash`` (the ids' 32-bit
+        keys) and the store's ``store.lookup``."""
         reg = self._obs()
-        t0 = time.perf_counter_ns() if reg.active else 0
-        self._poll_store()
-        keys = np_key_to_u32(np.asarray(session_ids))
-        plane = "pallas" if self.use_device_plane else "jnp"
-        if self.replicas_k > 1 and self._failed:
-            # k-replica sets in one device pass; same rule as route()
-            out = self._failover_pick(self.replica_set_batch(session_ids))
-        else:
-            out = self.image_store().lookup(keys, plane=plane,
-                                            block_rows=self.block_rows)
-        if reg.active:
-            reg.counter("router.batch_keys").inc(len(keys))
-            reg.histogram("router.route_batch.us").observe(
-                (time.perf_counter_ns() - t0) / 1e3)
-        return out
+        with reg.timed("router.route_batch", batch=reg.next_batch()):
+            self._poll_store()
+            with reg.timed("router.hash"):
+                keys = np_key_to_u32(np.asarray(session_ids))
+            plane = "pallas" if self.use_device_plane else "jnp"
+            if self.replicas_k > 1 and self._failed:
+                # k-replica sets in one device pass; same rule as route()
+                return self._failover_pick(self.replica_set_batch(session_ids))
+            return self.image_store().lookup(keys, plane=plane,
+                                             block_rows=self.block_rows)
 
     def replica_set_batch(self, session_ids: np.ndarray) -> np.ndarray:
         """k-replica sets for a session batch in one engine launch:
         int32 [len(ids), k], column 0 = the classic placement."""
-        from repro.core.hashing import np_key_to_u32
         reg = self._obs()
         t0 = time.perf_counter_ns() if reg.active else 0
         keys = np_key_to_u32(np.asarray(session_ids))
@@ -271,7 +270,6 @@ class SessionRouter:
         streams through the plane's pipelined double-buffered path; a
         replica-aware one dispatches per batch so the failover mask is
         applied with the same rule as the scalar path."""
-        from repro.core.hashing import np_key_to_u32
         reg = self._obs()
         plane = self.sharded_plane(mesh=mesh)
         if self.replicas_k == 1:
@@ -279,7 +277,9 @@ class SessionRouter:
                 for ids in session_id_batches:
                     self.stats.routed += len(ids)
                     reg.counter("router.stream_batches").inc()
-                    yield np_key_to_u32(np.asarray(ids))
+                    with reg.timed("router.hash"):
+                        keys = np_key_to_u32(np.asarray(ids))
+                    yield keys
 
             yield from plane.route_stream(to_keys())
             return
