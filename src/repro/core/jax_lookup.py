@@ -10,7 +10,10 @@ by the data/serving substrates for bulk routing.
 One lookup per algorithm (Memento, Anchor, Dx, Jump) over its flat
 :class:`~repro.core.protocol.DeviceImage`; :func:`lookup_image` dispatches.
 All loops are lane-synchronous masked ``lax.while_loop``s: a whole key
-block iterates until every lane settles.  Expected sweep counts: Memento
+block iterates until every lane settles.  Memento's loops carry the table
+word their condition tests, so each sweep is one ``repl`` gather over the
+block (a condition and its body are separate computations and would
+otherwise both gather it).  Expected sweep counts: Memento
 E[τ], E[σ] ≤ ln(n/w) (paper Props. VII.1-3); Anchor ≈ ln(a/w); Dx the
 geometric O(a/w) probe count.
 """
@@ -31,7 +34,9 @@ hash2_32 = hash2
 def memento_lookup(keys, repl, n):
     """Paper Alg. 4, vectorized: keys uint32 [...], repl int32 [cap], n int.
 
-    Returns int32 bucket ids in [0, n) that are working buckets.
+    Returns int32 bucket ids in [0, n) that are working buckets.  One
+    ``repl`` gather over the block starts the loops and one pays for each
+    sweep (:func:`_memento_loops`).
     """
     keys = jnp.asarray(keys).astype(_U)
     return _memento_loops(keys, repl, n, (), lambda acc, work: ())[0]
@@ -42,11 +47,11 @@ def memento_lookup_counted(keys, repl, n):
 
     Returns ``(buckets, sweeps, lane_sweeps)``: ``sweeps`` (int32) is the
     number of iterations of the outer and inner loops together, each one
-    round of dependent ``repl`` gathers over the whole block;
-    ``lane_sweeps`` (uint32, wraps at 2³²) sums, over the lanes, the
-    iterations in which the lane did work (outer: still on a removed
-    bucket; inner: still following the chain).  Their ratio over the
-    block size is the share of each sweep spent on unsettled lanes.
+    ``repl`` gather over the whole block; ``lane_sweeps`` (uint32, wraps
+    at 2³²) sums, over the lanes, the iterations in which the lane did
+    work (outer: still on a removed bucket; inner: still following the
+    chain).  Their ratio over the block size is the share of each sweep
+    spent on unsettled lanes.
     """
     keys = jnp.asarray(keys).astype(_U)
     b, (sweeps, lanes) = _memento_loops(
@@ -58,37 +63,42 @@ def memento_lookup_counted(keys, repl, n):
 def _memento_loops(keys, repl, n, acc, tally):
     """Alg. 4's two lane-synchronous loops; ``acc`` rides both loops'
     carries and ``tally(acc, work)`` folds in each iteration's mask of
-    lanes with work (``()`` and a no-op carry nothing extra)."""
+    lanes with work (``()`` and a no-op carry nothing extra).
+
+    Each carry holds the ``repl`` word of the index beside it (outer
+    ``c = repl[b]``, inner ``u = repl[d]``), so the conditions read only
+    carried values and each sweep is one ``repl`` gather: one before the
+    outer loop, one at each inner loop's entry, one per inner iteration.
+    The inner loop's last word is the next outer test's ``repl[b]``."""
     b = jump32(keys, n)
 
     def outer_cond(state):
-        b, _ = state
-        return jnp.any(repl[b] >= 0)
+        _, c, _ = state
+        return jnp.any(c >= 0)
 
     def outer_body(state):
-        b, acc = state
-        c = repl[b]
+        b, c, acc = state
         active = c >= 0
         wb = jnp.where(active, c, 1)  # |W_b| (Prop. V.3); dummy 1 when settled
         h = hash2(keys, b)
         d = (h % wb.astype(_U)).astype(jnp.int32)
 
         def inner_cond(state):
-            d, _ = state
-            u = repl[d]
+            _, u, _ = state
             return jnp.any(active & (u >= 0) & (u >= wb))
 
         def inner_body(state):
-            d, acc = state
-            u = repl[d]
+            d, u, acc = state
             follow = active & (u >= 0) & (u >= wb)  # only while u ≥ w_b (balance)
-            return jnp.where(follow, u, d), tally(acc, follow)
+            d = jnp.where(follow, u, d)
+            return d, repl[d], tally(acc, follow)
 
-        d, acc = jax.lax.while_loop(inner_cond, inner_body,
-                                    (d, tally(acc, active)))
-        return jnp.where(active, d, b), acc
+        d, u, acc = jax.lax.while_loop(inner_cond, inner_body,
+                                       (d, repl[d], tally(acc, active)))
+        return jnp.where(active, d, b), jnp.where(active, u, c), acc
 
-    return jax.lax.while_loop(outer_cond, outer_body, (b, acc))
+    b, _, acc = jax.lax.while_loop(outer_cond, outer_body, (b, repl[b], acc))
+    return b, acc
 
 
 def anchor_lookup(keys, A, K, a):

@@ -1,6 +1,8 @@
 """Host ⇄ device data-plane equivalence for batched Memento lookups."""
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -55,7 +57,8 @@ def test_floor_rn_quotient_corrects_an_inexact_divide(ulps):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-@pytest.mark.parametrize("n0,removals", [(16, 0), (16, 7), (128, 50), (1000, 400)])
+@pytest.mark.parametrize("n0,removals",
+                         [(16, 0), (16, 7), (128, 50), (1000, 400), (2000, 1800)])
 def test_jnp_memento_matches_host(keys, n0, removals):
     import jax.numpy as jnp
 
@@ -66,6 +69,44 @@ def test_jnp_memento_matches_host(keys, n0, removals):
     host = np.asarray([m.lookup(int(k)) for k in keys])
     np.testing.assert_array_equal(out, host)
     assert set(out.tolist()) <= ws
+
+
+_HLO_CALLEE = re.compile(r"(calls|to_apply|condition|body)=%?([\w.\-]+)")
+
+
+@pytest.mark.parametrize("counted", [False, True], ids=["plain", "counted"])
+def test_memento_sweep_is_one_repl_gather(counted):
+    """Each ``repl`` word is gathered once: no while condition gathers
+    (the word it tests rides the carry), and the program holds three
+    gather sites — before the loops, at each inner loop's entry, and in
+    the inner body."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch.hlo_analysis import analyze_jit
+
+    m = random_state(np.random.default_rng(5), 2000, 1800, variant="32")
+    repl = jnp.asarray(MementoTables(m).repl)
+    keys = jnp.asarray(np.random.default_rng(6).integers(
+        0, 2**32, size=4096, dtype=np.uint32))
+    fn = (jax_lookup.memento_lookup_counted if counted
+          else jax_lookup.memento_lookup)
+    comps = analyze_jit(jax.jit(fn), keys, repl, m.n).comps
+
+    def gathers(name, seen=frozenset()):
+        instrs = comps[name].instrs
+        callees = {c for ins in instrs
+                   for _, c in _HLO_CALLEE.findall(ins.rest)} - seen
+        return (sum(ins.opcode == "gather" for ins in instrs)
+                + sum(gathers(c, seen | {name}) for c in callees))
+
+    conds = {c for comp in comps.values() for ins in comp.instrs
+             for kind, c in _HLO_CALLEE.findall(ins.rest)
+             if ins.opcode == "while" and kind == "condition"}
+    assert len(conds) >= 2  # Memento's two loops (and Jump's)
+    assert {c: gathers(c) for c in conds} == dict.fromkeys(conds, 0)
+    assert sum(ins.opcode == "gather" for comp in comps.values()
+               for ins in comp.instrs) == 3
 
 
 def test_jnp_memento_balance(keys):
