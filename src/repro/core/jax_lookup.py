@@ -39,31 +39,37 @@ def memento_lookup(keys, repl, n):
     sweep (:func:`_memento_loops`).
     """
     keys = jnp.asarray(keys).astype(_U)
-    return _memento_loops(keys, repl, n, (), lambda acc, work: ())[0]
+    return _memento_loops(keys, repl, n, (), lambda acc, work, outer: ())[0]
 
 
 def memento_lookup_counted(keys, repl, n):
     """:func:`memento_lookup` that also counts its loops' work.
 
-    Returns ``(buckets, sweeps, lane_sweeps)``: ``sweeps`` (int32) is the
-    number of iterations of the outer and inner loops together, each one
-    ``repl`` gather over the whole block; ``lane_sweeps`` (uint32, wraps
-    at 2³²) sums, over the lanes, the iterations in which the lane did
-    work (outer: still on a removed bucket; inner: still following the
-    chain).  Their ratio over the block size is the share of each sweep
-    spent on unsettled lanes.
+    Returns ``(buckets, sweeps, lane_sweeps, outer_sweeps, longest_lane)``:
+    ``sweeps`` (int32) is the number of iterations of the outer and inner
+    loops together, each one ``repl`` gather over the whole block;
+    ``lane_sweeps`` (uint32, wraps at 2³²) sums, over the lanes, the
+    iterations in which the lane did work (outer: still on a removed
+    bucket; inner: still following the chain).  Their ratio over the
+    block size is the share of each sweep spent on unsettled lanes.
+    ``outer_sweeps`` (int32) counts the outer loop's iterations alone,
+    each of which starts a fresh inner loop; ``longest_lane`` (uint32) is
+    the most iterations any one lane did work in, the largest of the
+    per-lane counts ``lane_sweeps`` sums.
     """
     keys = jnp.asarray(keys).astype(_U)
-    b, (sweeps, lanes) = _memento_loops(
-        keys, repl, n, (jnp.int32(0), jnp.zeros_like(keys)),
-        lambda acc, work: (acc[0] + 1, acc[1] + work.astype(_U)))
-    return b, sweeps, jnp.sum(lanes, dtype=_U)
+    b, (sweeps, outer, lanes) = _memento_loops(
+        keys, repl, n, (jnp.int32(0), jnp.int32(0), jnp.zeros_like(keys)),
+        lambda acc, work, outer: (acc[0] + 1, acc[1] + int(outer),
+                                  acc[2] + work.astype(_U)))
+    return b, sweeps, jnp.sum(lanes, dtype=_U), outer, jnp.max(lanes, initial=0)
 
 
 def _memento_loops(keys, repl, n, acc, tally):
     """Alg. 4's two lane-synchronous loops; ``acc`` rides both loops'
-    carries and ``tally(acc, work)`` folds in each iteration's mask of
-    lanes with work (``()`` and a no-op carry nothing extra).
+    carries and ``tally(acc, work, outer)`` folds in each iteration's mask
+    of lanes with work, ``outer`` true for the outer loop's (``()`` and a
+    no-op carry nothing extra).
 
     Each carry holds the ``repl`` word of the index beside it (outer
     ``c = repl[b]``, inner ``u = repl[d]``), so the conditions read only
@@ -91,10 +97,10 @@ def _memento_loops(keys, repl, n, acc, tally):
             d, u, acc = state
             follow = active & (u >= 0) & (u >= wb)  # only while u ≥ w_b (balance)
             d = jnp.where(follow, u, d)
-            return d, repl[d], tally(acc, follow)
+            return d, repl[d], tally(acc, follow, False)
 
         d, u, acc = jax.lax.while_loop(inner_cond, inner_body,
-                                       (d, repl[d], tally(acc, active)))
+                                       (d, repl[d], tally(acc, active, True)))
         return jnp.where(active, d, b), jnp.where(active, u, c), acc
 
     b, _, acc = jax.lax.while_loop(outer_cond, outer_body, (b, repl[b], acc))

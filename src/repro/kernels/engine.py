@@ -117,9 +117,10 @@ def _obs_dispatch(reg, n_keys: int) -> None:
     reg.histogram("engine.batch_keys").observe(n_keys)
 
 
-#: the histograms the counted Memento program's two device counts feed
+#: the histograms the counted Memento program's four device counts feed
 #: (:func:`repro.core.jax_lookup.memento_lookup_counted`), one value a batch
-MEMENTO_SWEEP_HISTOGRAMS = ("engine.memento.sweeps", "engine.memento.lane_sweeps")
+MEMENTO_SWEEP_HISTOGRAMS = ("engine.memento.sweeps", "engine.memento.lane_sweeps",
+                            "engine.memento.outer_sweeps", "engine.memento.longest_lane")
 
 
 # ---------------------------------------------------------------------------
@@ -613,8 +614,9 @@ def _engine_jnp(blocks, arrays, scalars, load, cap, *, op: EngineOp):
             moved = moved | (o != n_)
         return tuple(outs), tuple(new), moved
     if _counts_sweeps(op):
-        b, sweeps, lanes = memento_lookup_counted(keys, tables[0], scalars[0])
-        return (b,), jnp.stack([sweeps.astype(_U), lanes])
+        b, sweeps, lanes, outer, longest = memento_lookup_counted(
+            keys, tables[0], scalars[0])
+        return (b,), jnp.stack([sweeps.astype(_U), lanes, outer.astype(_U), longest])
     return tuple(epoch_outs(tables[:nt], scalars[:op.num_scalars])), None
 
 

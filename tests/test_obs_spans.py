@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.core import make_hash
-from repro.kernels.engine import engine_lookup
+from repro.kernels.engine import MEMENTO_SWEEP_HISTOGRAMS, engine_lookup
 from repro.obs import MetricRegistry, default_registry, set_default_registry
 from repro.serve.router import SessionRouter
 
@@ -106,8 +106,7 @@ def test_engine_dispatch_lands_on_the_injected_registry():
     finally:
         set_default_registry(prev)
     got = {k.split("{")[0] for k in injected.snapshot()["histograms"]}
-    assert {"engine.dispatch.us", "engine.memento.sweeps",
-            "engine.memento.lane_sweeps"} <= got
+    assert {"engine.dispatch.us", *MEMENTO_SWEEP_HISTOGRAMS} <= got
     assert injected.counter("engine.lookups").value == 1
     assert not any(k.startswith("engine.")
                    for sec in default.snapshot().values() for k in sec)
@@ -119,13 +118,18 @@ def test_engine_dispatch_lands_on_the_injected_registry():
 
 def _replay_counts(keys, c, n):
     """Alg. 4's two loops over numpy arrays, lane-synchronous like the
-    device program: (buckets, loop iterations, Σ lanes with work)."""
+    device program: every lane takes each iteration of either loop, which
+    runs while any lane has work.  Returns the buckets, the iterations of
+    both loops, those of the outer loop, and each lane's iterations with
+    work (outer: still on a removed bucket; inner: following its chain)."""
     b = REF.jump32(keys, n).astype(np.int64)
-    sweeps = lanes = 0
+    steps = np.zeros(keys.size, np.int64)
+    sweeps = outer = 0
     while (c[b] >= 0).any():
         active = c[b] >= 0
         sweeps += 1
-        lanes += int(active.sum())
+        outer += 1
+        steps += active
         wb = np.where(active, c[b], 1)
         d = (REF.hash2(keys, b) % wb.astype(np.uint32)).astype(np.int64)
         while True:
@@ -133,34 +137,52 @@ def _replay_counts(keys, c, n):
             if not follow.any():
                 break
             sweeps += 1
-            lanes += int(follow.sum())
+            steps += follow
             d = np.where(follow, c[d], d)
         b = np.where(active, d, b)
-    return b, sweeps, lanes
+    return b, sweeps, outer, steps
 
 
-@pytest.mark.parametrize("removed_share", [0.0, 0.3, 0.9])
-def test_memento_sweep_counts_match_a_numpy_replay(removed_share):
-    n, n_keys = 5000, 4096
-    removed = np.random.default_rng(11).permutation(n)[:int(removed_share * n)]
+#: (removed share, buckets, keys a batch): below and past the paper's ~70%
+#: knee, at two fleet sizes and two batch sizes
+FLEETS = [pytest.param(0.0, 5000, 4096, id="0.0"),
+          pytest.param(0.3, 5000, 4096, id="0.3"),
+          pytest.param(0.9, 5000, 4096, id="0.9"),
+          pytest.param(0.3, 20_000, 1024, id="0.3-20000-1024"),
+          pytest.param(0.3, 20_000, 4096, id="0.3-20000-4096"),
+          pytest.param(0.9, 20_000, 1024, id="0.9-20000-1024"),
+          pytest.param(0.9, 20_000, 4096, id="0.9-20000-4096")]
+
+
+@pytest.mark.parametrize("removed_share,n,n_keys", FLEETS)
+def test_memento_sweep_counts_match_a_numpy_replay(removed_share, n, n_keys):
+    """The engine's four loop counts, one value each a batch, equal the
+    replay's, and its buckets are the replay's and the host's."""
     h = make_hash("memento", n, variant="32")
     ref = REF.Reference(n)
+    removed = np.random.default_rng(11).permutation(n)[:int(removed_share * n)]
     for b in removed.tolist():
         h.remove(b)
         ref.remove(b)
     keys = np.random.default_rng(12).integers(0, 2**32, n_keys, dtype=np.uint32)
-    want_b, want_sweeps, want_lanes = _replay_counts(keys, ref._c, ref.n)
+    want_b, want_sweeps, want_outer, steps = _replay_counts(keys, ref._c, ref.n)
     reg = MetricRegistry()
     out = np.asarray(engine_lookup(keys, h.device_image(), plane="jnp",
                                    registry=reg))
     reg.flush_device()
+    hists = {name.rsplit(".", 1)[1]: reg.histogram(name)
+             for name in MEMENTO_SWEEP_HISTOGRAMS}
+    assert {k: v.count for k, v in hists.items()} == dict.fromkeys(hists, 1)
+    assert {k: v.sum for k, v in hists.items()} == {
+        "sweeps": want_sweeps, "lane_sweeps": int(steps.sum()),
+        "outer_sweeps": want_outer, "longest_lane": int(steps.max())}
     np.testing.assert_array_equal(out, want_b)
-    sweeps = reg.histogram("engine.memento.sweeps")
-    lanes = reg.histogram("engine.memento.lane_sweeps")
-    assert (sweeps.count, lanes.count) == (1, 1)
-    assert (sweeps.sum, lanes.sum) == (want_sweeps, want_lanes)
+    np.testing.assert_array_equal(out, [h.lookup(int(k)) for k in keys])
+    if removed_share:
+        # each outer iteration waits for the slowest chain of any lane
+        assert want_sweeps > steps.max() >= want_outer >= 1
     if removed_share == 0.9:
-        assert want_sweeps > 10 and 0 < want_lanes < want_sweeps * n_keys
+        assert want_sweeps > 10 and 0 < steps.sum() < want_sweeps * n_keys
 
 
 def test_counts_are_queued_until_the_result_is_fetched():
