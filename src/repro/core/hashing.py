@@ -97,10 +97,47 @@ def np_fmix32(h: np.ndarray) -> np.ndarray:
     return h
 
 
+def _fmix32_inplace(h: np.ndarray, scratch: np.ndarray) -> None:
+    """`np_fmix32` on a uint32 array in place; ``scratch`` is a same-size
+    uint32 buffer for the shifts."""
+    np.right_shift(h, np.uint32(16), out=scratch)
+    h ^= scratch
+    np.multiply(h, np.uint32(_C1_32), out=h)
+    np.right_shift(h, np.uint32(13), out=scratch)
+    h ^= scratch
+    np.multiply(h, np.uint32(_C2_32), out=h)
+    np.right_shift(h, np.uint32(16), out=scratch)
+    h ^= scratch
+
+
+# Keys hashed per pass of `np_key_to_u32`: a block's ids (512 KB) and its
+# words (256 KB) stay in cache while every step of the hash runs over them.
+_KEY_BLOCK = 1 << 16
+
+
 def np_key_to_u32(keys: np.ndarray) -> np.ndarray:
-    """Vectorized `key_to_u32` for integer keys (matches the scalar path)."""
-    k = keys.astype(np.uint64)
-    return np_fmix32(((k & np.uint64(MASK32)) ^ (k >> np.uint64(32))).astype(np.uint32))
+    """Vectorized `key_to_u32` for integer keys (matches the scalar path).
+
+    Native, C-contiguous 8-byte ids are read in place as pairs of uint32
+    words; any other input is first converted once to uint64. The fold
+    (low word ^ high word, the same in either byte order) and `fmix32` run
+    block by block on the output, so each block is hashed while it sits
+    in cache. ``keys`` is never written.
+    """
+    k = np.asarray(keys)
+    # a dtype equals np.uint64 / np.int64 only in native byte order
+    if not (k.dtype in (np.uint64, np.int64) and k.flags.c_contiguous):
+        k = k.astype(np.uint64, order="C")
+    words = k.reshape(-1).view(np.uint32)
+    n = k.size
+    out = np.empty(n, np.uint32)
+    scratch = np.empty(min(n, _KEY_BLOCK), np.uint32)
+    for s in range(0, n, _KEY_BLOCK):
+        e = min(s + _KEY_BLOCK, n)
+        h = out[s:e]
+        np.bitwise_xor(words[2 * s:2 * e:2], words[2 * s + 1:2 * e:2], out=h)
+        _fmix32_inplace(h, scratch[:e - s])
+    return out.reshape(k.shape)
 
 
 def np_hash2_32(keys: np.ndarray, seed: np.ndarray | int) -> np.ndarray:
