@@ -224,9 +224,8 @@ def bench_device_scenarios(emit, w=1024, a_over_w=4, n_keys=8192,
     import jax
     import jax.numpy as jnp
     from repro.core.jax_lookup import lookup_image
-    from repro.kernels import ops
     from repro.kernels.engine import (EngineOp, default_interpret,
-                                      mosaic_compiles)
+                                      engine_lookup, mosaic_compiles)
 
     rng = np.random.default_rng(0)
     keys = jnp.asarray(rng.integers(0, 2**32, size=n_keys, dtype=np.uint32))
@@ -246,11 +245,11 @@ def bench_device_scenarios(emit, w=1024, a_over_w=4, n_keys=8192,
         emit(f"device_{scenario}_memory", h.name, x, "bytes", h.memory_bytes())
         if not (default_interpret() or mosaic_compiles(EngineOp(image.algo))):
             return  # Mosaic cannot compile this table-backed body (§6)
-        pout = ops.device_lookup(pkeys, image)  # interpret on CPU, Mosaic on TPU
+        pout = engine_lookup(pkeys, image)  # interpret on CPU, Mosaic on TPU
         pout.block_until_ready()
         np.testing.assert_array_equal(np.asarray(out)[:pallas_keys], np.asarray(pout))
         t0 = time.perf_counter()
-        ops.device_lookup(pkeys, image).block_until_ready()
+        engine_lookup(pkeys, image).block_until_ready()
         emit(f"device_{scenario}_lookup", h.name, x, "pallas_us_per_key",
              (time.perf_counter() - t0) / pallas_keys * 1e6)
 

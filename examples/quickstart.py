@@ -5,7 +5,7 @@
 import numpy as np
 
 from repro.core import AnchorHash, DxHash, JumpHash, MementoHash, PowerHash
-from repro.kernels import ops
+from repro.kernels.engine import engine_lookup
 
 
 def main():
@@ -30,7 +30,7 @@ def main():
     m.remove(7)
     m.remove(2)
     batch = np.random.default_rng(0).integers(0, 2**32, size=8, dtype=np.uint32)
-    out = ops.device_lookup(batch, m.device_image(), plane="auto")
+    out = engine_lookup(batch, m.device_image(), plane="auto")
     print("\nbatched device-plane lookups:", np.asarray(out).tolist())
 
     # 5. baselines for comparison (fixed capacity a = 10·w)
@@ -48,7 +48,7 @@ def main():
             h.remove(h.size - 1)
         else:
             h.remove(3)
-        out = ops.device_lookup(batch, h.device_image(), plane="auto")
+        out = engine_lookup(batch, h.device_image(), plane="auto")
         assert [h.lookup(int(k)) for k in batch] == np.asarray(out).tolist()
         print(f"  {algo:8s} → {np.asarray(out).tolist()}")
 

@@ -1,19 +1,21 @@
-"""Batched consistent-hash lookups — pure-jnp data plane.
+"""Batched consistent-hash lookups — the device lookup bodies.
 
 Bit-identical to the numpy/scalar host plane (``variant="32"`` states):
-the shared 32-bit arithmetic lives in :mod:`repro.kernels.primitives` and
-is consumed both here and by the Pallas kernels, so all three planes
-(host / jnp / Pallas) agree exactly.  These functions are the oracle for
-the kernels (``kernels/ref.py`` re-exports them) and the CPU fallback used
-by the data/serving substrates for bulk routing.
+the shared 32-bit arithmetic lives in :mod:`repro.kernels.primitives`.
+These are the ONLY lookup bodies for the table-backed algorithms
+(Memento, Anchor, Dx; Jump and Power are the shared primitives): the jnp
+programs, the Pallas kernel and the sharded plane all run them, through
+:func:`repro.kernels.engine.algo_body`, which picks the body and, for
+Memento, the table reader of each table layout.  ``kernels/ref.py``
+re-exports them as the kernels' oracle.
 
-One lookup per algorithm (Memento, Anchor, Dx, Jump) over its flat
+One lookup per algorithm over its flat
 :class:`~repro.core.protocol.DeviceImage`; :func:`lookup_image` dispatches.
 All loops are lane-synchronous masked ``lax.while_loop``s: a whole key
 block iterates until every lane settles.  Memento's loops carry the table
-word their condition tests, so each sweep is one ``repl`` gather over the
+word their condition tests, so each sweep is one table read over the
 block (a condition and its body are separate computations and would
-otherwise both gather it).  Expected sweep counts: Memento
+otherwise both read it).  Expected sweep counts: Memento
 E[τ], E[σ] ≤ ln(n/w) (paper Props. VII.1-3); Anchor ≈ ln(a/w); Dx the
 geometric O(a/w) probe count.
 """
@@ -22,13 +24,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.primitives import (fmix32, hash2, jump32, power32,
-                                      step_u24 as _step_u24)
+from repro.kernels.primitives import fmix32, hash2, jump32, power32
 
 _U = jnp.uint32
-
-# Back-compat alias: earlier revisions exposed ``hash2_32`` here.
-hash2_32 = hash2
 
 
 def memento_lookup(keys, repl, n):
@@ -39,7 +37,8 @@ def memento_lookup(keys, repl, n):
     sweep (:func:`_memento_loops`).
     """
     keys = jnp.asarray(keys).astype(_U)
-    return _memento_loops(keys, repl, n, (), lambda acc, work, outer: ())[0]
+    return _memento_loops(keys, lambda i: repl[i], n, (),
+                          lambda acc, work, outer: ())[0]
 
 
 def memento_lookup_counted(keys, repl, n):
@@ -59,23 +58,27 @@ def memento_lookup_counted(keys, repl, n):
     """
     keys = jnp.asarray(keys).astype(_U)
     b, (sweeps, outer, lanes) = _memento_loops(
-        keys, repl, n, (jnp.int32(0), jnp.int32(0), jnp.zeros_like(keys)),
+        keys, lambda i: repl[i], n,
+        (jnp.int32(0), jnp.int32(0), jnp.zeros_like(keys)),
         lambda acc, work, outer: (acc[0] + 1, acc[1] + int(outer),
                                   acc[2] + work.astype(_U)))
     return b, sweeps, jnp.sum(lanes, dtype=_U), outer, jnp.max(lanes, initial=0)
 
 
-def _memento_loops(keys, repl, n, acc, tally):
-    """Alg. 4's two lane-synchronous loops; ``acc`` rides both loops'
-    carries and ``tally(acc, work, outer)`` folds in each iteration's mask
-    of lanes with work, ``outer`` true for the outer loop's (``()`` and a
-    no-op carry nothing extra).
+def _memento_loops(keys, read, n, acc, tally):
+    """Alg. 4's two lane-synchronous loops over a table reader:
+    ``read(idx) -> int32`` is the ``repl`` word of each index (−1 =
+    working), a dense gather or a packed-image probe
+    (:func:`repro.kernels.engine.packed_reader`).  ``acc`` rides both
+    loops' carries and ``tally(acc, work, outer)`` folds in each
+    iteration's mask of lanes with work, ``outer`` true for the outer
+    loop's (``()`` and a no-op carry nothing extra).
 
-    Each carry holds the ``repl`` word of the index beside it (outer
-    ``c = repl[b]``, inner ``u = repl[d]``), so the conditions read only
-    carried values and each sweep is one ``repl`` gather: one before the
-    outer loop, one at each inner loop's entry, one per inner iteration.
-    The inner loop's last word is the next outer test's ``repl[b]``."""
+    Each carry holds the word of the index beside it (outer
+    ``c = read(b)``, inner ``u = read(d)``), so the conditions read only
+    carried values and each sweep is one read: one before the outer loop,
+    one at each inner loop's entry, one per inner iteration.  The inner
+    loop's last word is the next outer test's ``read(b)``."""
     b = jump32(keys, n)
 
     def outer_cond(state):
@@ -97,13 +100,13 @@ def _memento_loops(keys, repl, n, acc, tally):
             d, u, acc = state
             follow = active & (u >= 0) & (u >= wb)  # only while u ≥ w_b (balance)
             d = jnp.where(follow, u, d)
-            return d, repl[d], tally(acc, follow, False)
+            return d, read(d), tally(acc, follow, False)
 
         d, u, acc = jax.lax.while_loop(inner_cond, inner_body,
-                                       (d, repl[d], tally(acc, active, True)))
+                                       (d, read(d), tally(acc, active, True)))
         return jnp.where(active, d, b), jnp.where(active, u, c), acc
 
-    b, _, acc = jax.lax.while_loop(outer_cond, outer_body, (b, repl[b], acc))
+    b, _, acc = jax.lax.while_loop(outer_cond, outer_body, (b, read(b), acc))
     return b, acc
 
 
@@ -185,26 +188,10 @@ def lookup_dispatch(algo, keys, arrays, scalars):
 
 
 def lookup_image(keys, image):
-    """Dispatch a batched jnp lookup over any :class:`DeviceImage` (eager)."""
+    """Dispatch a batched jnp lookup over any :class:`DeviceImage` (eager):
+    the oracle tests hold the engine's planes to."""
     from repro.core.protocol import image_scalar_vec
 
     keys = jnp.asarray(keys, dtype=jnp.uint32)
     arrays = {k: jnp.asarray(v) for k, v in image.arrays.items()}
     return lookup_dispatch(image.algo, keys, arrays, image_scalar_vec(image))
-
-
-def lookup_image_jit(keys, image):
-    """Jitted :func:`lookup_image` — now a shim over the unified engine's
-    jnp configuration (kept for one release alongside the kernel shims):
-    compiles once per (algo, shapes) and is reused across epochs, since
-    the epoch store's stable 128-padded capacities make every churn event
-    shape-preserving."""
-    from repro.kernels.engine import engine_lookup
-
-    return engine_lookup(keys, image, plane="jnp")
-
-
-def memento_lookup_hosted(keys, memento_tables):
-    """Convenience: run the data plane against a host `MementoTables`."""
-    repl = jnp.asarray(memento_tables.repl)
-    return memento_lookup(jnp.asarray(keys, dtype=jnp.uint32), repl, memento_tables.n)

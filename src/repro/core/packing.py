@@ -11,7 +11,7 @@ VMEM-resident:
   in-capacity growth needs no bitmap writes) plus the Θ(r)
   open-addressing replacement table (``slot_b``, ``slot_c``) in the
   narrowest dtype that holds every bucket id.  The probe sequence is the
-  engine's ``compact_reader`` sequence (linear probing from
+  engine's ``packed_reader`` sequence (linear probing from
   ``fmix32(b·GOLDEN32 + 5) & mask``); deletions (bucket restores) leave
   TOMBSTONE slots the reader probes straight past, so epoch deltas edit
   the packed table in place.
@@ -76,7 +76,7 @@ def image_table_bytes(image) -> int:
 
 def _slot_count(r: int, *, headroom: int = 1) -> int:
     """Power-of-two slot count for r removed buckets: load factor ≤ 0.5 at
-    ``headroom=1`` (the probe-chain bound of ``compact_reader``), ≤ 0.25 at
+    ``headroom=1`` (the probe-chain bound of ``packed_reader``), ≤ 0.25 at
     the store's default ``headroom=2`` so delta-driven inserts have room."""
     nslots = 128
     while nslots < 2 * max(headroom, 1) * max(r, 1):

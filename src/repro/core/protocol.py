@@ -9,8 +9,8 @@ int32/uint32 arrays plus the dynamic scalars the lane-synchronous lookups
 need.  One image format serves three consumers:
 
   * ``core/jax_lookup.lookup_image``   — pure-jnp oracle (any backend),
-  * ``kernels/ops.device_lookup``      — Pallas kernels (Mosaic on TPU,
-    interpret mode elsewhere),
+  * ``kernels/engine.engine_lookup``   — the lookup engine's jnp and
+    Pallas planes (Mosaic on TPU, interpret mode elsewhere),
   * tests/benchmarks                   — cross-plane equivalence sweeps.
 
 Device control plane (DESIGN.md §3.5): membership churn is epoch-versioned.

@@ -3,8 +3,8 @@
 # static EngineOp configuration covers plain lookup, k-replication,
 # bounded-load (incl. the fused k-replica-under-cap op), chain-walk
 # assignment rounds, and one/two-epoch diffs for every registry algorithm.
-# ops.device_lookup is the public image-generic entry; primitives.py holds
-# the shared 32-bit hash arithmetic; ref.py the oracles kernel tests
-# compare against; delta_apply.py the epoch-delta scatter (§3.5).
-# engine.py is the only import surface: the PR-4 per-algorithm re-export
-# shims served their one release and are gone.
+# engine.engine_lookup is the public image-generic entry; the lookup
+# bodies it runs live in core/jax_lookup.py (engine.algo_body picks one);
+# primitives.py holds the shared 32-bit hash arithmetic; ref.py the
+# oracles kernel tests compare against; delta_apply.py the epoch-delta
+# scatter (§3.5).

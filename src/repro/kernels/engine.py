@@ -23,21 +23,24 @@ program).  The configuration space is :class:`EngineOp`:
                 — the round primitive of :func:`bounded_assign`
   =========== =====================================================
 
-Algorithms: ``memento`` (dense Θ(n) table or the beyond-paper compact
-Θ(r) open-addressing table), ``anchor`` (A/K arrays), ``dx`` (packed
-bitmap), ``jump`` (stateless).  The per-algorithm lookup bodies live HERE
-and only here; this module is the one import surface for device lookups
-(the per-algorithm re-export shims of the engine's first release are
-retired).
+Algorithms: ``memento`` (dense Θ(n) ``repl`` table, or the packed
+bitmap + Θ(r) open-addressing slots), ``anchor`` (A/K arrays, narrowed
+when packed), ``dx`` (packed bitmap), ``jump`` and ``power`` (stateless).
+The per-algorithm lookup bodies live in :mod:`repro.core.jax_lookup`,
+once each; :func:`algo_body` is the one place that picks a body and, for
+packed Memento, its table reader, and :func:`_mode_outputs` the one op
+runner every plane calls.  This module is the one import surface for
+device lookups.
 
 Planes: ``plane='pallas'`` (Mosaic on TPU for the configurations
 :func:`mosaic_compiles` admits — any other refuses at dispatch —,
-interpret elsewhere) and
+interpret elsewhere),
 ``plane='jnp'`` (pure-jnp, any backend; also the per-shard body the
 mesh-sharded :class:`~repro.serve.plane.ShardedLookupPlane` runs under
-``shard_map``).  Both are bit-identical to the host control plane on
-``variant="32"`` states — the bodies are the exact ones the pre-engine
-kernels ran, block padding included.
+``shard_map``) and ``plane='auto'`` (the autotuner's pick,
+:func:`repro.kernels.autotune.resolve_plane`).  Both planes run the same
+bodies and are bit-identical to the host control plane on
+``variant="32"`` states.
 """
 from __future__ import annotations
 
@@ -52,12 +55,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bounded import accept_in_index_order, walk_probe_bound
 from repro.core.hashing import GOLDEN32
-from repro.core.jax_lookup import lookup_dispatch, memento_lookup_counted
-from repro.core.packing import PACKED_LAYOUT, build_slots
+from repro.core.jax_lookup import (_memento_loops, anchor_lookup,
+                                   lookup_dispatch, memento_lookup_counted)
+from repro.core.packing import PACKED_LAYOUT, image_table_names
 from repro.core.protocol import (ALGORITHMS, IMAGE_LAYOUT, REPLICA_SALT_CAP,
                                  image_scalar_vec)
 from repro.obs.metrics import default_registry as _obs_registry
-from .primitives import fmix32, gather1d, hash2, jump32, power32, table_shape2d
+from .primitives import fmix32, gather1d, hash2, table_shape2d
 
 _U = jnp.uint32
 
@@ -140,10 +144,9 @@ class EngineOp:
       above the prefetched load cap (fused k-replica × bounded-load),
     * ``diff``    — lookup mode: run under two epoch images in the same
       launch and emit the moved mask (k>1 diffs whole replica sets),
-    * ``table``   — "dense" (full-width layout), "packed" (the compact
+    * ``table``   — "dense" (full-width layout) or "packed" (the compact
       :mod:`repro.core.packing` layout of a ``packed=True`` image; any
-      algorithm, any mode), or — memento only — "compact" (the legacy
-      per-call Θ(r) open addressing; lookup mode).
+      algorithm, any mode).
     """
 
     algo: str
@@ -162,18 +165,12 @@ class EngineOp:
             raise ValueError("k must be ≥ 1")
         if self.mode == "walk" and (self.k != 1 or self.diff or self.bounded):
             raise ValueError("walk mode is k=1, no diff, cap-implicit")
-        if self.table not in ("dense", "compact", "packed"):
+        if self.table not in ("dense", "packed"):
             raise ValueError(f"unknown table kind {self.table!r}")
-        if self.table == "compact" and self.algo != "memento":
-            raise ValueError("compact tables are Memento-only")
-        if self.table == "compact" and (self.diff or self.mode == "walk"):
-            raise ValueError("compact tables serve lookup mode only")
 
     # -- derived operand layout ---------------------------------------------
     @property
     def table_names(self) -> tuple[str, ...]:
-        if self.table == "compact":
-            return ("slot_b", "slot_c")
         if self.table == "packed":
             return PACKED_LAYOUT[self.algo][1]
         return IMAGE_LAYOUT[self.algo][1]
@@ -198,88 +195,19 @@ class EngineOp:
 
 
 # ---------------------------------------------------------------------------
-# The per-algorithm lookup bodies (the ONLY copies in the repo)
+# Body and table-reader selection (the bodies live in core/jax_lookup.py)
 # ---------------------------------------------------------------------------
 
-def memento_body(keys, read, n):
-    """Paper Alg. 4, lane-synchronous, over an abstract table reader.
-
-    ``read(idx) -> int32`` returns ``repl[idx]`` (−1 = working).  The dense
-    plane reads by VMEM gather, the compact plane by open-addressing probe
-    — one body, two table layouts (DESIGN.md §3.2).
-    """
-
-    b = jump32(keys, n)
-
-    def outer_cond(b):
-        return jnp.any(read(b) >= 0)
-
-    def outer_body(b):
-        c = read(b)
-        active = c >= 0
-        wb = jnp.where(active, c, 1)  # |W_b| after b was removed (Prop. V.3)
-        d = (hash2(keys, b) % wb.astype(_U)).astype(jnp.int32)
-
-        def inner_cond(d):
-            u = read(d)
-            return jnp.any(active & (u >= 0) & (u >= wb))
-
-        def inner_body(d):
-            u = read(d)
-            follow = active & (u >= 0) & (u >= wb)  # follow only while u ≥ w_b
-            return jnp.where(follow, u, d)
-
-        d = jax.lax.while_loop(inner_cond, inner_body, d)
-        return jnp.where(active, d, b)
-
-    return jax.lax.while_loop(outer_cond, outer_body, b)
-
-
-def dense_body(keys, repl, n):
-    """Memento dense-table body: flat VMEM repl image + dynamic n."""
-    return memento_body(keys, lambda idx: gather1d(repl, idx), n)
-
-
-def compact_reader(slot_b, slot_c):
-    """``read(idx)`` over the Θ(r) open-addressing image: linear probing
-    from ``fmix32(idx·GOLDEN32 + 5) & mask`` until hit (→ c) or empty
-    (→ −1, the bucket is working)."""
-    nslots = slot_b.shape[0]  # power of two
-    mask = _U(nslots - 1)
-
-    def read(idx):
-        h0 = (fmix32(idx.astype(_U) * _U(GOLDEN32) + _U(5)) & mask).astype(jnp.int32)
-
-        def cond(state):
-            pos, done, _ = state
-            return jnp.any(~done)
-
-        def body(state):
-            pos, done, val = state
-            sb = gather1d(slot_b, pos)
-            hit = sb == idx
-            empty = sb < 0
-            val = jnp.where(~done & hit, gather1d(slot_c, pos), val)
-            done = done | hit | empty
-            pos = jnp.where(done, pos, (pos + 1) % nslots)
-            return pos, done, val
-
-        val0 = jnp.full_like(idx, -1, jnp.int32)
-        done0 = jnp.zeros_like(idx, jnp.bool_)
-        _, _, val = jax.lax.while_loop(cond, body, (h0, done0, val0))
-        return val
-
-    return read
-
-
 def packed_reader(state, slot_b, slot_c):
-    """``read(idx)`` over the packed Memento image (DESIGN.md §8.2): the
-    uint32 ``state`` bitmap short-circuits working buckets (bit = 1 → −1,
-    no probe at all — the overwhelmingly common case), removed buckets
-    probe the open-addressing slots with the ``compact_reader`` sequence
-    but stop only on EMPTY (−1): TOMBSTONE (−2) slots left by epoch-delta
-    restores keep the chain alive.  Slot words may be dtype-narrowed;
-    values widen to int32 at the gather."""
+    """``read(idx)`` over the packed Memento image (DESIGN.md §8.2), for
+    :func:`repro.core.jax_lookup._memento_loops`: the uint32 ``state``
+    bitmap short-circuits working buckets (bit = 1 → −1, no probe at all —
+    the overwhelmingly common case), removed buckets probe the
+    open-addressing slots by linear probing from
+    ``fmix32(idx·GOLDEN32 + 5) & mask`` and stop only on EMPTY (−1):
+    TOMBSTONE (−2) slots left by epoch-delta restores keep the chain
+    alive.  Slot words may be dtype-narrowed; values widen to int32 at
+    the gather."""
     nslots = slot_b.shape[0]  # power of two
     mask = _U(nslots - 1)
 
@@ -310,78 +238,22 @@ def packed_reader(state, slot_b, slot_c):
     return read
 
 
-def anchor_body(keys, A, K, a):
-    """AnchorHash body: A (removal stamps) / K (wrap successors) in VMEM."""
-    b = (fmix32(keys) % a.astype(_U)).astype(jnp.int32)
-
-    def outer_cond(b):
-        return jnp.any(gather1d(A, b) > 0)
-
-    def outer_body(b):
-        Ab = gather1d(A, b)
-        active = Ab > 0
-        denom = jnp.where(active, Ab, 1).astype(_U)
-        h = (hash2(keys, b) % denom).astype(jnp.int32)
-
-        def inner_cond(h):
-            return jnp.any(active & (gather1d(A, h) >= Ab))
-
-        def inner_body(h):
-            follow = active & (gather1d(A, h) >= Ab)  # removed at-or-after b
-            return jnp.where(follow, gather1d(K, h), h)
-
-        h = jax.lax.while_loop(inner_cond, inner_body, h)
-        return jnp.where(active, h, b)
-
-    return jax.lax.while_loop(outer_cond, outer_body, b)
-
-
-def dx_body(keys, words, a, max_probes, fallback):
-    """DxHash body: pseudo-random probing of the packed active bitmap."""
-    b0 = jnp.zeros_like(keys, jnp.int32)
-    found0 = jnp.zeros_like(keys, jnp.bool_)
-
-    def cond(state):
-        i, _, found = state
-        return (i < max_probes) & jnp.any(~found)
-
-    def body(state):
-        i, b, found = state
-        cand = (hash2(keys, i) % a.astype(_U)).astype(jnp.int32)
-        w = gather1d(words, cand >> 5)
-        bit = (w >> (cand & 31).astype(_U)) & _U(1)
-        hit = ~found & (bit == _U(1))
-        return i + jnp.int32(1), jnp.where(hit, cand, b), found | hit
-
-    _, b, found = jax.lax.while_loop(cond, body, (jnp.int32(0), b0, found0))
-    return jnp.where(found, b, fallback)
-
-
 def algo_body(op: EngineOp, keys, tables, scalars):
-    """One-epoch lookup body dispatch — shared by every op mode so plain
-    lookups, replicas, bounded assignment, and epoch diffs can never
-    disagree about placement."""
-    if op.algo == "memento":
-        if op.table == "compact":
-            return memento_body(keys, compact_reader(tables[0], tables[1]),
-                                scalars[0])
-        if op.table == "packed":
-            return memento_body(
-                keys, packed_reader(tables[0], tables[1], tables[2]),
-                scalars[0])
-        return dense_body(keys, tables[0], scalars[0])
-    if op.algo == "anchor":
-        # packed tables may be dtype-narrowed; widen at the boundary (a
-        # no-op trace-wise for the dense int32 layout)
-        return anchor_body(keys, tables[0].astype(jnp.int32),
-                           tables[1].astype(jnp.int32), scalars[0])
-    if op.algo == "dx":
-        return dx_body(keys, tables[0], scalars[0], scalars[1], scalars[2])
-    if op.algo == "jump":
-        return jump32(keys, scalars[0])
-    if op.algo == "power":
-        return power32(keys, scalars[0])
-    raise ValueError(f"unknown algo {op.algo!r}")
+    """One-epoch lookup of ``op`` over its table layout — the one place a
+    body and a table reader are picked, shared by every op mode and plane
+    so plain lookups, replicas, bounded assignment and epoch diffs can
+    never disagree about placement.  Dense tables (and every layout a
+    packed image shares with the dense one) go through
+    :func:`~repro.core.jax_lookup.lookup_dispatch`."""
+    if op.table == "packed" and op.algo == "memento":
+        return _memento_loops(keys, packed_reader(*tables), scalars[0], (),
+                              lambda acc, work, outer: ())[0]
+    if op.table == "packed" and op.algo == "anchor":
+        # packed A/K are dtype-narrowed; widen at the boundary
+        return anchor_lookup(keys, tables[0].astype(jnp.int32),
+                             tables[1].astype(jnp.int32), scalars[0])
+    return lookup_dispatch(op.algo, keys, dict(zip(op.table_names, tables)),
+                           scalars)
 
 
 # ---------------------------------------------------------------------------
@@ -477,11 +349,14 @@ def chain_walk_body(chain, probe, pending, load, cap, single_lookup):
 
 
 def _mode_outputs(op: EngineOp, blocks, tables, scalars, load, cap):
-    """Run the configured op over one key block; returns the output list.
+    """Run the configured op over one key block — the one op runner of
+    the Pallas kernel, the jnp program and the sharded plane's shards.
 
     ``blocks`` is (keys,) in lookup mode, (chain, probe, pending) in walk
     mode; ``tables``/``scalars`` hold one epoch's operands, or two epochs
-    concatenated when ``op.diff``.
+    concatenated when ``op.diff``.  Returns the output list: k buckets per
+    epoch plus the bool moved mask (diff), or (b, chain uint32, probe)
+    (walk).
     """
     nt, ns = op.num_tables, op.num_scalars
     if op.mode == "walk":
@@ -489,7 +364,7 @@ def _mode_outputs(op: EngineOp, blocks, tables, scalars, load, cap):
         b, chain, probe = chain_walk_body(
             chain, probe, pending != 0, load, cap,
             lambda kk: algo_body(op, kk, tables, scalars))
-        return [b, chain.astype(jnp.int32), probe]
+        return [b, chain, probe]
     keys = blocks[0]
 
     def epoch_outs(tabs, scals):
@@ -503,7 +378,7 @@ def _mode_outputs(op: EngineOp, blocks, tables, scalars, load, cap):
         moved = jnp.zeros(keys.shape, jnp.bool_)
         for o, n_ in zip(outs, new):
             moved = moved | (o != n_)
-        outs = outs + new + [moved.astype(jnp.int32)]
+        outs = outs + new + [moved]
     return outs
 
 
@@ -537,8 +412,10 @@ def _engine_kernel_factory(op: EngineOp):
         if op.mode == "walk":
             blocks[0] = blocks[0].astype(_U)
         outs = _mode_outputs(op, blocks, tables, scalars, load, cap)
+        # output blocks are int32: the bool moved mask and the uint32 walk
+        # chain convert at the store
         for ref, o in zip(out_refs, outs):
-            ref[...] = o
+            ref[...] = o.astype(jnp.int32)
 
     return kernel
 
@@ -575,49 +452,19 @@ def _engine_pallas(scalars, blocks2d, tables2d, *, op: EngineOp,
 
 # ---------------------------------------------------------------------------
 # jnp plane: one jitted program per configuration (traced operands, so one
-# compile serves every epoch of a given shape).  Lookup mode returns
-# ``(outs, counts)``: the plain dense Memento lookup counts its loops' work
-# on the device, in the same program whether or not anyone reads it.
+# compile serves every epoch of a given shape).  It returns ``(outs,
+# counts)``: ``outs`` as :func:`_mode_outputs` lays them out, and the plain
+# dense Memento lookup counts its loops' work on the device, in the same
+# program whether or not anyone reads it.
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("op",))
 def _engine_jnp(blocks, arrays, scalars, load, cap, *, op: EngineOp):
-    def dispatch(tabs, scals):
-        if op.table == "packed":
-            # the packed layout has no jax_lookup oracle — its one body
-            # lives in algo_body, shared with the Pallas plane
-            return lambda kk: algo_body(op, kk, list(tabs), list(scals))
-        arrs = dict(zip(names, tabs))
-        return lambda kk: lookup_dispatch(op.algo, kk, arrs, scals)
-
-    nt = op.num_tables
-    tables = list(arrays)
-    names = op.table_names  # rebuild named dicts for lookup_dispatch per epoch
-    if op.mode == "walk":
-        chain, probe, pending = blocks
-        b, chain, probe = chain_walk_body(
-            chain, probe, pending, load, cap,
-            dispatch(tables[:nt], scalars[:op.num_scalars]))
-        return b, chain, probe
-    keys = blocks[0]
-
-    def epoch_outs(tabs, scals):
-        return replica_body(keys, op.k, dispatch(tabs, scals),
-                            load=load if op.bounded else None, cap=cap)
-
-    if op.diff:
-        outs = epoch_outs(tables[:nt], scalars[:op.num_scalars])
-        new = epoch_outs(tables[nt:2 * nt],
-                         scalars[op.num_scalars:2 * op.num_scalars])
-        moved = jnp.zeros(keys.shape, jnp.bool_)
-        for o, n_ in zip(outs, new):
-            moved = moved | (o != n_)
-        return tuple(outs), tuple(new), moved
     if _counts_sweeps(op):
         b, sweeps, lanes, outer, longest = memento_lookup_counted(
-            keys, tables[0], scalars[0])
+            blocks[0], arrays[0], scalars[0])
         return (b,), jnp.stack([sweeps.astype(_U), lanes, outer.astype(_U), longest])
-    return tuple(epoch_outs(tables[:nt], scalars[:op.num_scalars])), None
+    return tuple(_mode_outputs(op, blocks, arrays, scalars, load, cap)), None
 
 
 def _counts_sweeps(op: EngineOp) -> bool:
@@ -643,10 +490,6 @@ def _op_table(image, table: str = "dense") -> str:
 
 
 def _image_tables(op: EngineOp, image):
-    if op.table == "compact":
-        slot_b, slot_c = build_compact_table(
-            jnp.asarray(image.arrays["repl"], jnp.int32))
-        return [slot_b, slot_c]
     if (op.table == "packed") != bool(getattr(image, "packed", False)):
         raise ValueError(f"table={op.table!r} op cannot read a "
                          f"{'packed' if image.packed else 'dense'} image")
@@ -669,9 +512,7 @@ def _scalar_vec(op: EngineOp, images, cap):
 def _jnp_operands(images):
     arrays, scalars = [], []
     for img in images:
-        layout = PACKED_LAYOUT if getattr(img, "packed", False) else IMAGE_LAYOUT
-        names = layout[img.algo][1]
-        arrays += [jnp.asarray(img.arrays[n]) for n in names]
+        arrays += [jnp.asarray(img.arrays[n]) for n in image_table_names(img)]
         scalars += [jnp.asarray(s, jnp.int32) for s in image_scalar_vec(img)]
     return tuple(arrays), tuple(scalars)
 
@@ -690,7 +531,8 @@ def engine_lookup(keys, image, *, k: int = 1, load=None, cap: int | None = None,
     passing ``load``/``cap`` fuses the bounded-load rejection into the same
     single launch (every returned bucket has ``load < cap``, slot 0
     included).  Bit-identical to the host plane on ``variant="32"`` states.
-    Telemetry lands on ``registry``, else on the process default; the
+    ``plane`` is "pallas", "jnp" or "auto" (the autotuner's pick for this
+    op, batch and table size).  Telemetry lands on ``registry``, else on the process default; the
     Memento loop counts are queued on it (``observe_device``) for the
     caller to flush once it has fetched the result.
     """
@@ -702,9 +544,11 @@ def engine_lookup(keys, image, *, k: int = 1, load=None, cap: int | None = None,
     reg = registry if registry is not None else _obs_registry()
     with _dispatch_span(reg, op):
         keys = jnp.asarray(keys, dtype=_U)
+        if plane == "auto":
+            from . import autotune  # lazy: autotune ↔ engine would cycle
+            plane = autotune.resolve_plane(op, int(keys.shape[0]),
+                                           int(image.n))
         if plane == "jnp":
-            if table == "compact":
-                raise ValueError("jnp plane serves the dense layout")
             arrays, scalars = _jnp_operands([image])
             outs, counts = _engine_jnp(
                 (keys,), arrays, scalars,
@@ -810,55 +654,48 @@ def _engine_diff(keys, old_image, new_image, *, k: int = 1,
                  plane: str = "jnp", interpret: bool | None = None,
                  block_rows: int | None = None) -> EngineDiff:
     keys = jnp.asarray(keys, dtype=_U)
-    if plane == "jnp":
-        if old_image.algo != new_image.algo:
-            # cross-algorithm migration: two dispatches, still one program
-            op_old = EngineOp(algo=old_image.algo, k=k,
-                              table=_op_table(old_image))
-            op_new = EngineOp(algo=new_image.algo, k=k,
-                              table=_op_table(new_image))
-            ao, so = _jnp_operands([old_image])
-            an, sn = _jnp_operands([new_image])
-            old, _ = _engine_jnp((keys,), ao, so, None, None, op=op_old)
-            new, _ = _engine_jnp((keys,), an, sn, None, None, op=op_new)
-            old_np = _stack_np(old, k)
-            new_np = _stack_np(new, k)
-            moved = (old_np != new_np) if k == 1 else \
-                (old_np != new_np).any(axis=1)
-            return EngineDiff(old_np, new_np, np.asarray(moved))
-        if bool(getattr(old_image, "packed", False)) != \
-                bool(getattr(new_image, "packed", False)):
-            raise ValueError("epoch diff needs both images in one layout")
-        op = EngineOp(algo=old_image.algo, k=k, diff=True,
-                      table=_op_table(old_image))
-        arrays, scalars = _jnp_operands([old_image, new_image])
-        old, new, moved = _engine_jnp((keys,), arrays, scalars, None, None,
-                                      op=op)
-        return EngineDiff(_stack_np(old, k), _stack_np(new, k),
-                          np.asarray(moved))
-    if plane != "pallas":
+    if plane not in ("jnp", "pallas"):
         raise ValueError(f"unknown plane {plane!r}")
     if old_image.algo != new_image.algo:
-        raise ValueError("pallas epoch diff requires one algorithm "
-                         f"({old_image.algo!r} != {new_image.algo!r})")
+        if plane == "pallas":
+            raise ValueError("pallas epoch diff requires one algorithm "
+                             f"({old_image.algo!r} != {new_image.algo!r})")
+        # cross-algorithm migration: two dispatches, still one program
+        op_old = EngineOp(algo=old_image.algo, k=k,
+                          table=_op_table(old_image))
+        op_new = EngineOp(algo=new_image.algo, k=k,
+                          table=_op_table(new_image))
+        ao, so = _jnp_operands([old_image])
+        an, sn = _jnp_operands([new_image])
+        old, _ = _engine_jnp((keys,), ao, so, None, None, op=op_old)
+        new, _ = _engine_jnp((keys,), an, sn, None, None, op=op_new)
+        old_np = _stack_np(old, k)
+        new_np = _stack_np(new, k)
+        moved = (old_np != new_np) if k == 1 else \
+            (old_np != new_np).any(axis=1)
+        return EngineDiff(old_np, new_np, np.asarray(moved))
     if bool(getattr(old_image, "packed", False)) != \
             bool(getattr(new_image, "packed", False)):
         raise ValueError("epoch diff needs both images in one layout")
     op = EngineOp(algo=old_image.algo, k=k, diff=True,
                   table=_op_table(old_image))
-    if interpret is None:
-        interpret = default_interpret()
-    tables = _image_tables(op, old_image) + _image_tables(op, new_image)
-    keys2d, nk = _pad_rows(keys)
-    outs = _engine_pallas(_scalar_vec(op, [old_image, new_image], None),
-                          (keys2d,), tuple(_tables2d(tables)), op=op,
-                          block_rows=_resolve_block_rows(
-                              op, nk, int(new_image.n), block_rows),
-                          interpret=interpret)
-    flat = [np.asarray(o.reshape(-1)[:nk]) for o in outs]
-    old = flat[0] if k == 1 else np.stack(flat[:k]).T
-    new = flat[k] if k == 1 else np.stack(flat[k:2 * k]).T
-    return EngineDiff(old, new, flat[2 * k].astype(bool))
+    if plane == "jnp":
+        arrays, scalars = _jnp_operands([old_image, new_image])
+        outs, _ = _engine_jnp((keys,), arrays, scalars, None, None, op=op)
+        flat = [np.asarray(o) for o in outs]
+    else:
+        if interpret is None:
+            interpret = default_interpret()
+        tables = _image_tables(op, old_image) + _image_tables(op, new_image)
+        keys2d, nk = _pad_rows(keys)
+        outs = _engine_pallas(_scalar_vec(op, [old_image, new_image], None),
+                              (keys2d,), tuple(_tables2d(tables)), op=op,
+                              block_rows=_resolve_block_rows(
+                                  op, nk, int(new_image.n), block_rows),
+                              interpret=interpret)
+        flat = [np.asarray(o.reshape(-1)[:nk]) for o in outs]
+    return EngineDiff(_stack_np(flat[:k], k), _stack_np(flat[k:2 * k], k),
+                      flat[2 * k].astype(bool))
 
 
 def _stack_np(outs, k):
@@ -883,8 +720,9 @@ def engine_chain_walk(chain, probe, pending, image, load, cap, *,
         nk = chain.shape[0]
         if plane == "jnp":
             arrays, scalars = _jnp_operands([image])
-            b, ch, pr = _engine_jnp((chain, probe, pending), arrays, scalars,
-                                    load, jnp.asarray(cap, jnp.int32), op=op)
+            outs, _ = _engine_jnp((chain, probe, pending), arrays, scalars,
+                                  load, jnp.asarray(cap, jnp.int32), op=op)
+            b, ch, pr = (np.asarray(o) for o in outs)
         elif plane != "pallas":
             raise ValueError(f"unknown plane {plane!r}")
         else:
@@ -894,20 +732,17 @@ def engine_chain_walk(chain, probe, pending, image, load, cap, *,
             probe2d, _ = _pad_rows(probe)
             pending2d, _ = _pad_rows(pending.astype(jnp.int32))
             tables = _image_tables(op, image) + [load]
-            b, ch, pr = _engine_pallas(
+            outs = _engine_pallas(
                 _scalar_vec(op, [image], cap), (chain2d, probe2d, pending2d),
                 tuple(_tables2d(tables)), op=op,
                 block_rows=_resolve_block_rows(op, nk, int(image.n),
                                                block_rows),
                 interpret=interpret)
+            b, ch, pr = (np.asarray(o.reshape(-1)[:nk]) for o in outs)
     if reg.active:
         reg.counter("engine.walk_steps").inc()
         _obs_dispatch(reg, nk)
-    if plane == "jnp":
-        return (np.asarray(b), np.asarray(ch).astype(np.uint32),
-                np.asarray(pr))
-    take = lambda x: np.asarray(x.reshape(-1)[:nk])  # noqa: E731
-    return take(b), take(ch).astype(np.uint32), take(pr)
+    return b, ch.astype(np.uint32), pr
 
 
 def bounded_assign(keys, image, load, cap: int, *, plane: str = "jnp",
@@ -980,19 +815,3 @@ def bounded_replica_sets(h, keys, k: int, load, cap: int) -> np.ndarray:
     for i, key in enumerate(keys):
         out[i] = h.lookup_k_filtered(int(key), k, reject, check_first=True)
     return out
-
-
-# ---------------------------------------------------------------------------
-# Host-side compact-table builder (memento, beyond-paper Θ(r) image)
-# ---------------------------------------------------------------------------
-
-def build_compact_table(repl) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Host-side: dense repl image → open-addressing (slot_b, slot_c) arrays.
-
-    Slots = next power of two ≥ max(2r, 128) → load factor ≤ 0.5, so the
-    expected probe chain is ~1.5 and the VMEM working set is Θ(r).  The
-    insertion algorithm (and the packed-image variant with headroom and
-    dtype narrowing) lives in :func:`repro.core.packing.build_slots`.
-    """
-    slot_b, slot_c = build_slots(np.asarray(repl))
-    return jnp.asarray(slot_b), jnp.asarray(slot_c)

@@ -1,7 +1,7 @@
 """Pure-jnp + scalar-host oracles for the Pallas lookup kernels.
 
-The jnp reference implementations live in :mod:`repro.core.jax_lookup`
-(they are also the production CPU fallback); re-exported here so kernel
+The jnp lookup bodies live in :mod:`repro.core.jax_lookup` (every
+device plane runs them, through ``kernels/engine.algo_body``); re-exported here so kernel
 tests read naturally as ``kernel(...) == ref(...)``.  The scalar host
 oracle works for ANY ConsistentHash implementation (Memento, Anchor, Dx,
 Jump) — end-to-end cross-plane checks run host vs jnp vs Pallas.

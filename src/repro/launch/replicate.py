@@ -645,7 +645,7 @@ class FollowerImageStore:
 
     def lookup(self, keys, *, k: int = 1, **kw) -> np.ndarray:
         """Bulk lookup against the replicated image (unified engine —
-        packed replicas dispatch the compact reader, no dense decode)."""
+        packed replicas dispatch the packed reader, no dense decode)."""
         from repro.kernels.engine import engine_lookup
 
         reg = self._obs()

@@ -29,7 +29,6 @@ check).
 """
 from __future__ import annotations
 
-import functools
 import time
 
 import numpy as np
@@ -285,9 +284,8 @@ def sharded_lookup_program(op, mesh, axes: tuple[str, ...], *, plane: str,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.jax_lookup import lookup_dispatch
-    from repro.kernels.engine import (_engine_pallas, _pad_rows, _tables2d,
-                                      algo_body, replica_body)
+    from repro.kernels.engine import (_engine_pallas, _mode_outputs,
+                                      _pad_rows, _tables2d)
 
     names = op.table_names
     shard_dim = axes if len(axes) > 1 else axes[0]
@@ -298,14 +296,8 @@ def sharded_lookup_program(op, mesh, axes: tuple[str, ...], *, plane: str,
         # shape) can alias the donated input; bitcast restores uint32.
         keys = jax.lax.bitcast_convert_type(keys, jnp.uint32)
         if plane == "jnp":
-            if op.table == "packed":
-                body = lambda kk: algo_body(op, kk,
-                                            [arrays[n] for n in names],
-                                            list(scalars))
-            else:
-                body = lambda kk: lookup_dispatch(op.algo, kk,
-                                                  arrays, scalars)
-            outs = replica_body(keys, op.k, body)
+            outs = _mode_outputs(op, (keys,), [arrays[n] for n in names],
+                                 scalars, None, None)
         else:  # one Pallas launch per shard, tables in VMEM
             keys2d, nk = _pad_rows(keys)
             tabs = tuple(_tables2d([arrays[n] for n in names]))

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from conformance import state
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.engine import engine_lookup
 
 
 @pytest.mark.parametrize("algo", ["anchor", "dx"])
@@ -22,7 +23,7 @@ def test_kernel_block_rows_sweep(algo):
     keys = np.random.default_rng(8).integers(0, 2**32, size=1500, dtype=np.uint32)
     want = ref.lookup_host(keys, h)
     for block_rows in (1, 4, 16):
-        got = np.asarray(ops.device_lookup(keys, image, block_rows=block_rows))
+        got = np.asarray(engine_lookup(keys, image, block_rows=block_rows))
         np.testing.assert_array_equal(got, want)
 
 
@@ -32,7 +33,7 @@ def test_dx_fallback_path():
     image = h.device_image()
     image.scalars = dict(image.scalars, max_probes=1)  # force overruns
     keys = np.random.default_rng(10).integers(0, 2**32, size=400, dtype=np.uint32)
-    out = np.asarray(ops.device_lookup(keys, image))
+    out = np.asarray(engine_lookup(keys, image))
     assert set(out.tolist()) <= h.working_set()
     assert (out == image.scalars["fallback"]).any()
 
@@ -40,4 +41,4 @@ def test_dx_fallback_path():
 def test_device_lookup_rejects_unknown_plane():
     h = state("memento", 16, 0, seed=0)
     with pytest.raises(ValueError):
-        ops.device_lookup(np.zeros(4, np.uint32), h.device_image(), plane="cuda")
+        engine_lookup(np.zeros(4, np.uint32), h.device_image(), plane="cuda")

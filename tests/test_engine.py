@@ -4,8 +4,8 @@ The (algorithm × op-mode × plane) bit-identity matrix — plain lookup,
 k-replica, fused bounded-replica, bounded assignment, epoch diff — lives
 in ``tests/test_conformance.py`` now, derived from the registry.  This
 module keeps what is NOT a per-algorithm conformance cell: the engine's
-error surfaces, Memento's compact table mode, cross-algorithm diffs, and
-the mesh-sharded serving plane on top of the engine.
+error surfaces, cross-algorithm diffs, and the mesh-sharded serving
+plane on top of the engine.
 """
 from __future__ import annotations
 
@@ -46,22 +46,13 @@ def test_bounded_replica_infeasible_cap_raises(plane):
                              plane=plane)
 
 
-def test_memento_compact_all_modes():
-    h = state("memento", 200, 130, seed=7)
-    image = h.device_image()
-    host = ref.lookup_host(KEYS, h)
-    out = np.asarray(engine.engine_lookup(KEYS, image, plane="pallas",
-                                          table="compact"))
-    np.testing.assert_array_equal(out, host)
-
-
 def test_engine_op_validation():
     with pytest.raises(ValueError):
         engine.EngineOp("cuckoo")
     with pytest.raises(ValueError):
         engine.EngineOp("memento", k=0)
     with pytest.raises(ValueError):
-        engine.EngineOp("anchor", table="compact")
+        engine.EngineOp("memento", table="compact")
     with pytest.raises(ValueError):
         engine.EngineOp("memento", mode="walk", k=2)
     h = state("memento", 16, 0, seed=0)
@@ -72,10 +63,10 @@ def test_engine_op_validation():
 
 
 def test_shim_modules_are_gone():
-    """The PR-4 re-export shims were retired after their one release: the
-    engine is the only import surface for device lookups."""
+    """The per-algorithm re-export shims and the ``ops`` wrapper are gone:
+    the engine is the only import surface for device lookups."""
     for mod in ("memento_lookup", "anchor_lookup", "dx_lookup",
-                "jump_lookup", "replica_lookup", "migrate"):
+                "jump_lookup", "replica_lookup", "migrate", "ops"):
         with pytest.raises(ImportError):
             __import__(f"repro.kernels.{mod}")
 

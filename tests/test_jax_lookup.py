@@ -74,12 +74,14 @@ def test_jnp_memento_matches_host(keys, n0, removals):
 _HLO_CALLEE = re.compile(r"(calls|to_apply|condition|body)=%?([\w.\-]+)")
 
 
-@pytest.mark.parametrize("counted", [False, True], ids=["plain", "counted"])
-def test_memento_sweep_is_one_repl_gather(counted):
-    """Each ``repl`` word is gathered once: no while condition gathers
-    (the word it tests rides the carry), and the program holds three
-    gather sites — before the loops, at each inner loop's entry, and in
-    the inner body."""
+@pytest.mark.parametrize("variant", ["plain", "counted", "packed"])
+def test_memento_sweep_is_one_repl_gather(variant):
+    """Each ``repl`` word is read once: no while condition gathers or
+    loops (the word it tests rides the carry, so a table reader is called
+    only in the loop bodies).  Over the dense table the program holds
+    three gather sites — before the loops, at each inner loop's entry,
+    and in the inner body; over the packed image (the engine's jnp
+    program) each read is a bitmap gather and a slot probe loop."""
     import jax
     import jax.numpy as jnp
 
@@ -89,24 +91,35 @@ def test_memento_sweep_is_one_repl_gather(counted):
     repl = jnp.asarray(MementoTables(m).repl)
     keys = jnp.asarray(np.random.default_rng(6).integers(
         0, 2**32, size=4096, dtype=np.uint32))
-    fn = (jax_lookup.memento_lookup_counted if counted
-          else jax_lookup.memento_lookup)
-    comps = analyze_jit(jax.jit(fn), keys, repl, m.n).comps
+    if variant == "packed":
+        from repro.core.packing import pack_image
+        from repro.kernels.engine import EngineOp, _engine_jnp, _jnp_operands
 
-    def gathers(name, seen=frozenset()):
+        arrays, scalars = _jnp_operands([pack_image(m.device_image())])
+        comps = analyze_jit(_engine_jnp, (keys,), arrays, scalars, None, None,
+                            static={"op": EngineOp("memento", table="packed")}
+                            ).comps
+    else:
+        fn = (jax_lookup.memento_lookup_counted if variant == "counted"
+              else jax_lookup.memento_lookup)
+        comps = analyze_jit(jax.jit(fn), keys, repl, m.n).comps
+
+    def reached(name, opcode, seen=frozenset()):
         instrs = comps[name].instrs
         callees = {c for ins in instrs
                    for _, c in _HLO_CALLEE.findall(ins.rest)} - seen
-        return (sum(ins.opcode == "gather" for ins in instrs)
-                + sum(gathers(c, seen | {name}) for c in callees))
+        return (sum(ins.opcode == opcode for ins in instrs)
+                + sum(reached(c, opcode, seen | {name}) for c in callees))
 
     conds = {c for comp in comps.values() for ins in comp.instrs
              for kind, c in _HLO_CALLEE.findall(ins.rest)
              if ins.opcode == "while" and kind == "condition"}
     assert len(conds) >= 2  # Memento's two loops (and Jump's)
-    assert {c: gathers(c) for c in conds} == dict.fromkeys(conds, 0)
-    assert sum(ins.opcode == "gather" for comp in comps.values()
-               for ins in comp.instrs) == 3
+    for opcode in ("gather", "while"):
+        assert {c: reached(c, opcode) for c in conds} == dict.fromkeys(conds, 0)
+    if variant != "packed":
+        assert sum(ins.opcode == "gather" for comp in comps.values()
+                   for ins in comp.instrs) == 3
 
 
 def test_jnp_memento_balance(keys):

@@ -29,24 +29,32 @@ def test_dense_kernel_matches_oracle(n0, removals, nkeys):
 
 
 @pytest.mark.parametrize("n0,removals", [(16, 6), (1024, 30), (100000, 200)])
-def test_compact_kernel_matches_oracle(n0, removals):
+def test_packed_kernel_matches_oracle(n0, removals):
     import jax.numpy as jnp
+
+    from repro.core.packing import pack_image
 
     m, tabs = _state(n0, removals, seed=7)
     keys = np.random.default_rng(2).integers(0, 2**32, size=777, dtype=np.uint32)
-    got = np.asarray(engine_lookup(keys, m.device_image(), plane="pallas",
-                                   table="compact"))
+    got = np.asarray(engine_lookup(keys, pack_image(m.device_image()),
+                                   plane="pallas"))
     want = np.asarray(ref.memento_lookup_ref(jnp.asarray(keys), jnp.asarray(tabs.repl), m.n))
     np.testing.assert_array_equal(got, want)
 
 
-def test_compact_table_is_theta_r():
-    from repro.kernels.engine import build_compact_table
+def test_packed_slots_are_theta_r():
+    """The packed slot table grows with the removed set, not the fleet:
+    at least 2·headroom·r slots (load factor ≤ 1/(2·headroom)), at most
+    the next power of two above that (and never below 128)."""
+    from repro.core.packing import pack_image
 
-    m, tabs = _state(100000, 50, seed=3)
-    slot_b, slot_c = build_compact_table(tabs.repl)
-    assert slot_b.shape[0] <= 256  # 2·r rounded to a power of two ≥ 128
-    assert int((np.asarray(slot_b) >= 0).sum()) == len(m.R)
+    m, _ = _state(100000, 50, seed=3)
+    r = len(m.R)
+    for headroom in (1, 2):
+        slot_b = pack_image(m.device_image(),
+                            slot_headroom=headroom).arrays["slot_b"]
+        assert 2 * headroom * r <= slot_b.shape[0] <= max(128, 4 * headroom * r)
+        assert int((slot_b >= 0).sum()) == r
 
 
 @pytest.mark.parametrize("dtype", [np.uint32, np.int64, np.uint64])
