@@ -12,10 +12,12 @@ re-exports them as the kernels' oracle.
 One lookup per algorithm over its flat
 :class:`~repro.core.protocol.DeviceImage`; :func:`lookup_image` dispatches.
 All loops are lane-synchronous masked ``lax.while_loop``s: a whole key
-block iterates until every lane settles.  Memento's loops carry the table
-word their condition tests, so each sweep is one table read over the
-block (a condition and its body are separate computations and would
-otherwise both read it).  Expected sweep counts: Memento
+block iterates until every lane settles.  Memento runs Alg. 4's two loops
+as one, each lane in its own phase, so a block takes as many sweeps as
+its slowest key takes steps; the loop carries the table word its
+condition tests, so each sweep is one table read over the block (a
+condition and its body are separate computations and would otherwise
+both read it).  Expected sweep counts: Memento
 E[τ], E[σ] ≤ ln(n/w) (paper Props. VII.1-3); Anchor ≈ ln(a/w); Dx the
 geometric O(a/w) probe count.
 """
@@ -33,81 +35,79 @@ def memento_lookup(keys, repl, n):
     """Paper Alg. 4, vectorized: keys uint32 [...], repl int32 [cap], n int.
 
     Returns int32 bucket ids in [0, n) that are working buckets.  One
-    ``repl`` gather over the block starts the loops and one pays for each
-    sweep (:func:`_memento_loops`).
+    ``repl`` gather over the block starts the loop and one pays for each
+    sweep (:func:`_memento_loop`).
     """
     keys = jnp.asarray(keys).astype(_U)
-    return _memento_loops(keys, lambda i: repl[i], n, (),
-                          lambda acc, work, outer: ())[0]
+    return _memento_loop(keys, lambda i: repl[i], n, (),
+                          lambda acc, work, rehash: ())[0]
 
 
 def memento_lookup_counted(keys, repl, n):
-    """:func:`memento_lookup` that also counts its loops' work.
+    """:func:`memento_lookup` that also counts its loop's work.
 
     Returns ``(buckets, sweeps, lane_sweeps, outer_sweeps, longest_lane)``:
-    ``sweeps`` (int32) is the number of iterations of the outer and inner
-    loops together, each one ``repl`` gather over the whole block;
-    ``lane_sweeps`` (uint32, wraps at 2³²) sums, over the lanes, the
-    iterations in which the lane did work (outer: still on a removed
-    bucket; inner: still following the chain).  Their ratio over the
-    block size is the share of each sweep spent on unsettled lanes.
-    ``outer_sweeps`` (int32) counts the outer loop's iterations alone,
-    each of which starts a fresh inner loop; ``longest_lane`` (uint32) is
-    the most iterations any one lane did work in, the largest of the
-    per-lane counts ``lane_sweeps`` sums.
+    ``sweeps`` (int32) is the number of iterations of the loop, each one
+    ``repl`` gather over the whole block; ``lane_sweeps`` (uint32, wraps
+    at 2³²) sums, over the lanes, the iterations in which the lane did
+    work (a re-hash or a chain follow).  Their ratio over the block size
+    is the share of each sweep spent on unsettled lanes.
+    ``outer_sweeps`` (int32) is the most re-hash steps of any one lane
+    (the most outer iterations of Alg. 4 any key takes); ``longest_lane``
+    (uint32) is the most iterations any one lane did work in, the largest
+    of the per-lane counts ``lane_sweeps`` sums.  The loop runs until
+    its slowest lane settles, so ``sweeps == longest_lane``.
     """
     keys = jnp.asarray(keys).astype(_U)
-    b, (sweeps, outer, lanes) = _memento_loops(
-        keys, lambda i: repl[i], n,
-        (jnp.int32(0), jnp.int32(0), jnp.zeros_like(keys)),
-        lambda acc, work, outer: (acc[0] + 1, acc[1] + int(outer),
-                                  acc[2] + work.astype(_U)))
-    return b, sweeps, jnp.sum(lanes, dtype=_U), outer, jnp.max(lanes, initial=0)
+    zero = jnp.zeros_like(keys)
+    b, (sweeps, lanes, rehashes) = _memento_loop(
+        keys, lambda i: repl[i], n, (jnp.int32(0), zero, zero),
+        lambda acc, work, rehash: (acc[0] + 1, acc[1] + work.astype(_U),
+                                   acc[2] + rehash.astype(_U)))
+    return (b, sweeps, jnp.sum(lanes, dtype=_U),
+            jnp.max(rehashes, initial=0).astype(jnp.int32),
+            jnp.max(lanes, initial=0))
 
 
-def _memento_loops(keys, read, n, acc, tally):
-    """Alg. 4's two lane-synchronous loops over a table reader:
+def _memento_loop(keys, read, n, acc, tally):
+    """Alg. 4 as one lane-synchronous loop over a table reader:
     ``read(idx) -> int32`` is the ``repl`` word of each index (−1 =
     working), a dense gather or a packed-image probe
-    (:func:`repro.kernels.engine.packed_reader`).  ``acc`` rides both
-    loops' carries and ``tally(acc, work, outer)`` folds in each
-    iteration's mask of lanes with work, ``outer`` true for the outer
-    loop's (``()`` and a no-op carry nothing extra).
+    (:func:`repro.kernels.engine.packed_reader`).  ``acc`` rides the
+    carry and ``tally(acc, work, rehash)`` folds in each iteration's
+    masks of lanes with work and of lanes that re-hashed (``()`` and a
+    no-op carry nothing extra).
 
-    Each carry holds the word of the index beside it (outer
-    ``c = read(b)``, inner ``u = read(d)``), so the conditions read only
-    carried values and each sweep is one read: one before the outer loop,
-    one at each inner loop's entry, one per inner iteration.  The inner
-    loop's last word is the next outer test's ``read(b)``."""
-    b = jump32(keys, n)
+    Each lane keeps its own phase of Alg. 4's two loops, so the block
+    runs as many sweeps as its slowest key takes steps.  The carry is
+    ``(d, w, u)`` with ``u = read(d)``: the candidate, the working-set
+    size ``w_b`` of the last re-hash, and the candidate's word.  A lane
+    with ``u < 0`` has settled on ``d``.  Otherwise it takes one step:
+    it follows its chain (``d = u``) while ``u ≥ w`` (the inner loop),
+    else it re-hashes from ``b = d`` into ``w = u`` buckets (the outer
+    loop's next iteration, whose test ``read(b)`` is the word in hand).
+    ``w`` starts above any word, so a lane's first step is a re-hash.
+    The condition reads only the carried word, and each sweep is one
+    read: one before the loop and one per iteration."""
+    d = jump32(keys, n)
+    w = jnp.full_like(d, jnp.iinfo(jnp.int32).max)
 
-    def outer_cond(state):
-        _, c, _ = state
-        return jnp.any(c >= 0)
+    def cond(state):
+        _, _, u, _ = state
+        return jnp.any(u >= 0)
 
-    def outer_body(state):
-        b, c, acc = state
-        active = c >= 0
-        wb = jnp.where(active, c, 1)  # |W_b| (Prop. V.3); dummy 1 when settled
-        h = hash2(keys, b)
-        d = (h % wb.astype(_U)).astype(jnp.int32)
+    def body(state):
+        d, w, u, acc = state
+        work = u >= 0
+        follow = work & (u >= w)  # only while u ≥ w_b (balance)
+        rehash = work & ~follow
+        w = jnp.where(rehash, u, w)  # |W_b| (Prop. V.3), ≥ 1
+        h = (hash2(keys, d) % w.astype(_U)).astype(jnp.int32)
+        d = jnp.where(follow, u, jnp.where(rehash, h, d))
+        return d, w, read(d), tally(acc, work, rehash)
 
-        def inner_cond(state):
-            _, u, _ = state
-            return jnp.any(active & (u >= 0) & (u >= wb))
-
-        def inner_body(state):
-            d, u, acc = state
-            follow = active & (u >= 0) & (u >= wb)  # only while u ≥ w_b (balance)
-            d = jnp.where(follow, u, d)
-            return d, read(d), tally(acc, follow, False)
-
-        d, u, acc = jax.lax.while_loop(inner_cond, inner_body,
-                                       (d, read(d), tally(acc, active, True)))
-        return jnp.where(active, d, b), jnp.where(active, u, c), acc
-
-    b, _, acc = jax.lax.while_loop(outer_cond, outer_body, (b, read(b), acc))
-    return b, acc
+    d, _, _, acc = jax.lax.while_loop(cond, body, (d, w, read(d), acc))
+    return d, acc
 
 
 def anchor_lookup(keys, A, K, a):

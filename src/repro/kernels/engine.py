@@ -55,7 +55,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bounded import accept_in_index_order, walk_probe_bound
 from repro.core.hashing import GOLDEN32
-from repro.core.jax_lookup import (_memento_loops, anchor_lookup,
+from repro.core.jax_lookup import (_memento_loop, anchor_lookup,
                                    lookup_dispatch, memento_lookup_counted)
 from repro.core.packing import PACKED_LAYOUT, image_table_names
 from repro.core.protocol import (ALGORITHMS, IMAGE_LAYOUT, REPLICA_SALT_CAP,
@@ -200,7 +200,7 @@ class EngineOp:
 
 def packed_reader(state, slot_b, slot_c):
     """``read(idx)`` over the packed Memento image (DESIGN.md §8.2), for
-    :func:`repro.core.jax_lookup._memento_loops`: the uint32 ``state``
+    :func:`repro.core.jax_lookup._memento_loop`: the uint32 ``state``
     bitmap short-circuits working buckets (bit = 1 → −1, no probe at all —
     the overwhelmingly common case), removed buckets probe the
     open-addressing slots by linear probing from
@@ -246,8 +246,8 @@ def algo_body(op: EngineOp, keys, tables, scalars):
     packed image shares with the dense one) go through
     :func:`~repro.core.jax_lookup.lookup_dispatch`."""
     if op.table == "packed" and op.algo == "memento":
-        return _memento_loops(keys, packed_reader(*tables), scalars[0], (),
-                              lambda acc, work, outer: ())[0]
+        return _memento_loop(keys, packed_reader(*tables), scalars[0], (),
+                             lambda acc, work, rehash: ())[0]
     if op.table == "packed" and op.algo == "anchor":
         # packed A/K are dtype-narrowed; widen at the boundary
         return anchor_lookup(keys, tables[0].astype(jnp.int32),

@@ -1,13 +1,18 @@
 """Host ⇄ device data-plane equivalence for batched Memento lookups."""
 from __future__ import annotations
 
+import functools
+import importlib.util
 import re
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.core import MementoHash, MementoTables, np_jump32, random_state
 from repro.core import jax_lookup
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +76,79 @@ def test_jnp_memento_matches_host(keys, n0, removals):
     assert set(out.tolist()) <= ws
 
 
+def _load_reference():
+    spec = importlib.util.spec_from_file_location(
+        "t_memento_reference", ROOT / "bench" / "references" / "memento.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+#: the benchmark's plain numpy Memento (imports nothing of the program)
+REF = _load_reference()
+
+
+FLEET_KEYS = np.random.default_rng(8).integers(0, 2**32, size=512,
+                                               dtype=np.uint32)
+
+
+@functools.lru_cache(maxsize=None)
+def _fleet(removed_share, n):
+    """A ``variant="32"`` Memento of ``n`` buckets with ``removed_share``
+    of them removed at random, and the host's buckets for
+    :data:`FLEET_KEYS`, which the plain reference's equal."""
+    m = MementoHash(n, variant="32")
+    ref = REF.Reference(n)
+    order = np.random.default_rng(int(n * removed_share) + 7).permutation(n)
+    for b in order[:int(removed_share * n)].tolist():
+        m.remove(b)
+        ref.remove(b)
+    host = np.asarray([m.lookup(int(k)) for k in FLEET_KEYS])
+    np.testing.assert_array_equal(ref.lookup(FLEET_KEYS), host)
+    return m, host
+
+
+@pytest.mark.parametrize("plane,packed", [
+    pytest.param("jnp", False, id="jnp-dense"),
+    pytest.param("jnp", True, id="jnp-packed"),
+    pytest.param("pallas", False, id="pallas-interpret")])
+@pytest.mark.parametrize("n", [2000, 20_000])
+@pytest.mark.parametrize("removed_share", [0.0, 0.3, 0.7, 0.9, 0.99])
+def test_memento_planes_match_host_and_reference(removed_share, n, plane,
+                                                 packed):
+    """The one Memento loop gives the host's and the plain reference's
+    bucket for every key, below and past the ~70% knee, on each plane."""
+    from repro.core.packing import pack_image
+    from repro.kernels.engine import engine_lookup
+
+    m, host = _fleet(removed_share, n)
+    image = pack_image(m.device_image()) if packed else m.device_image()
+    out = engine_lookup(FLEET_KEYS, image, plane=plane, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), host)
+
+
+@pytest.mark.parametrize("removed_share", [0.3, 0.9, 0.99])
+def test_memento_counted_sweeps_equal_the_longest_lane(removed_share):
+    """Each lane keeps its own phase, so a batch runs exactly as many
+    sweeps as its slowest key takes steps, on every batch; the other
+    counts are consistent with it."""
+    import jax
+    import jax.numpy as jnp
+
+    m, _ = _fleet(removed_share, 20_000)
+    repl = jnp.asarray(MementoTables(m).repl)
+    counted = jax.jit(jax_lookup.memento_lookup_counted)
+    for seed in range(4):
+        keys = np.random.default_rng(100 + seed).integers(
+            0, 2**32, size=1024 << seed % 2, dtype=np.uint32)
+        b, sweeps, lanes, outer, longest = (
+            np.asarray(x) for x in counted(jnp.asarray(keys), repl, m.n))
+        assert int(sweeps) == int(longest) >= int(outer) >= 1
+        assert int(sweeps) < int(lanes) < int(sweeps) * keys.size
+        np.testing.assert_array_equal(
+            b, jax_lookup.memento_lookup(jnp.asarray(keys), repl, m.n))
+
+
 _HLO_CALLEE = re.compile(r"(calls|to_apply|condition|body)=%?([\w.\-]+)")
 
 
@@ -78,10 +156,10 @@ _HLO_CALLEE = re.compile(r"(calls|to_apply|condition|body)=%?([\w.\-]+)")
 def test_memento_sweep_is_one_repl_gather(variant):
     """Each ``repl`` word is read once: no while condition gathers or
     loops (the word it tests rides the carry, so a table reader is called
-    only in the loop bodies).  Over the dense table the program holds
-    three gather sites — before the loops, at each inner loop's entry,
-    and in the inner body; over the packed image (the engine's jnp
-    program) each read is a bitmap gather and a slot probe loop."""
+    only in the loop body).  Over the dense table the program holds two
+    gather sites — before the loop and in its body; over the packed
+    image (the engine's jnp program) each read is a bitmap gather and a
+    slot probe loop."""
     import jax
     import jax.numpy as jnp
 
@@ -114,12 +192,12 @@ def test_memento_sweep_is_one_repl_gather(variant):
     conds = {c for comp in comps.values() for ins in comp.instrs
              for kind, c in _HLO_CALLEE.findall(ins.rest)
              if ins.opcode == "while" and kind == "condition"}
-    assert len(conds) >= 2  # Memento's two loops (and Jump's)
+    assert len(conds) >= 2  # Memento's loop and Jump's
     for opcode in ("gather", "while"):
         assert {c: reached(c, opcode) for c in conds} == dict.fromkeys(conds, 0)
     if variant != "packed":
         assert sum(ins.opcode == "gather" for comp in comps.values()
-                   for ins in comp.instrs) == 3
+                   for ins in comp.instrs) == 2
 
 
 def test_jnp_memento_balance(keys):
